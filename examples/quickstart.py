@@ -54,7 +54,7 @@ def main():
           f"bq={plan.bq} bkv={plan.bkv} depth={plan.pipeline_depth} "
           f"dtype={plan.dtype} interpret={plan.resolve_interpret()} "
           f"({plan.predicted_gbps:.0f} GB/s predicted, {plan.source})")
-    print(f"  cached in {repr(default_cache().path)} "
+    print(f"  cached in {default_cache().path or 'memory'} "
           f"— kernels pick this up when called without blocks")
 
     print("\n=== 4. five training steps of a reduced gemma2 ===")

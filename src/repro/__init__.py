@@ -5,11 +5,6 @@ Reproduction of "Optimizing Memory Performance of Xilinx FPGAs under Vitis"
 """
 __version__ = "1.0.0"
 
-from repro import compat as _compat
-
-_compat.install()
-del _compat
-
 # the closed tune->execute loop is part of the public surface:
 # ``import repro; repro.tune.plan_for(...)``
-from repro import tune  # noqa: E402,F401
+from repro import tune  # noqa: F401

@@ -33,6 +33,9 @@ class TPUSpec:
     ici_bw: float = 50e9                  # bytes/s per link (collective term)
     hbm_bytes: int = 16 * 2**30           # capacity per chip
     vmem_bytes: int = 128 * 2**20         # on-chip buffer budget (BRAM analogue)
+    # what one Pallas kernel may hold in VMEM at once: the compiler's
+    # default scoped limit, far below the physical buffer above
+    scoped_vmem_bytes: int = 16 * 2**20
     clock_hz: float = 940e6
     # modeled DMA transaction latency (HBM row + controller + DMA setup).
     # The FPGA paper measures 58 cycles idle / ~107 loaded at 300MHz-class

@@ -217,8 +217,6 @@ class CellCost:
 
 def cost_of(compiled) -> CellCost:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jaxlib returns [dict] per device
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     txt = compiled.as_text()

@@ -25,7 +25,6 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.optim import adamw, compress
@@ -109,13 +108,13 @@ def make_dp_train_step(loss_fn: Callable, mesh,
                     f"slices but mesh axis {axis_name!r} has {n_shards} "
                     f"shard(s); build them with init_error_feedback(params, "
                     f"num_devices={n_shards})")
-        fn = shard_map(
-            local_step, mesh,
+        fn = jax.shard_map(
+            local_step, mesh=mesh,
             in_specs=(rep(params), rep(opt_state), err_specs(err),
                       batch_specs(batch)),
             out_specs=(rep(params), rep(opt_state), err_specs(err),
                        P()),
-            check_rep=False)
+            check_vma=False)
         new_p, new_opt, new_err, metrics = fn(params, opt_state, err, batch)
         if compress_grads:
             metrics = dict(metrics,

@@ -95,18 +95,24 @@ class ServeMesh:
         return dataclasses.replace(bundle, flags=flags)
 
     # ------------------------------------------------------------------
-    def shard_params(self, bundle, params):
+    def param_shardings(self, bundle):
         abs_params, specs = bundle.abstract_params()
-        shardings = self.policy.param_shardings(self.mesh, abs_params, specs)
-        return jax.device_put(params, shardings)
+        return self.policy.param_shardings(self.mesh, abs_params, specs)
+
+    def shard_params(self, bundle, params):
+        """Place existing params (a restored checkpoint, a test's tree) in
+        their TP shardings; a no-op for params already placed there (see
+        ``launch.serve.init_params``, which draws them in place)."""
+        return jax.device_put(params, self.param_shardings(bundle))
 
     def replicated(self, x):
         return jax.device_put(x, NamedSharding(self.mesh, P()))
 
     def paged_cache_shardings(self, cache):
-        """NamedSharding tree for a paged cache: k/v pools partitioned on
-        their kv-heads dim (axis ndim-2: pools are (..., pages, page_size,
-        Hkv, head_dim), stacked or not), the rest replicated."""
+        """NamedSharding tree for a paged cache (arrays or their
+        ShapeDtypeStructs): k/v pools partitioned on their kv-heads dim
+        (axis ndim-2: pools are (..., pages, page_size, Hkv, head_dim),
+        stacked or not), the rest replicated."""
 
         def one(path, leaf):
             if _leaf_name(path) in _POOL_LEAVES and leaf.ndim >= 4:
@@ -116,9 +122,6 @@ class ServeMesh:
             return NamedSharding(self.mesh, P())
 
         return jax.tree_util.tree_map_with_path(one, cache)
-
-    def shard_paged_cache(self, cache):
-        return jax.device_put(cache, self.paged_cache_shardings(cache))
 
     # ------------------------------------------------------------------
     def page_swap_shardings(self, cache):

@@ -19,8 +19,9 @@ Three serving-path extensions share the one kernel body:
   tokens left from a rotated-out page land on "future" positions and mask
   away for free.
 - ``k_scale``/``v_scale`` — int8 KV pages carry a per-token fp32 scale lane
-  per page ``(P, page)``; dequantization is fused into the score/value
-  loads, so the HBM stream stays at the paper's halved unit size.
+  per page ``(P, page)``; dequantization is fused into the kernel (each
+  token's scale multiplies its score column and its probability column),
+  so the HBM stream stays at the paper's halved unit size.
 """
 from __future__ import annotations
 
@@ -58,10 +59,9 @@ def _kernel(table_ref, vlen_ref, q_ref, kp_ref, vp_ref, *rest,
     q = q_ref[0].astype(jnp.float32) * scale                 # (g, d)
     k = kp_ref[0].astype(jnp.float32)                        # (page, d)
     v = vp_ref[0].astype(jnp.float32)
-    if quant:
-        k = k * ks_ref[0][:, None]
-        v = v * vs_ref[0][:, None]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (g, page)
+    if quant:
+        s = s * ks_ref[0]          # (1, page): token j's scale on column j
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
     if window is None:
@@ -86,7 +86,8 @@ def _kernel(table_ref, vlen_ref, q_ref, kp_ref, vp_ref, *rest,
     p = jnp.where(msk, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(p, v)
+    pv = p * vs_ref[0] if quant else p
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(pv, v)
     m_ref[...] = m_new
 
     @pl.when(j == n_pages - 1)
@@ -149,7 +150,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         return (table_ref[b_, j] * hkv + h_, 0, 0)
 
     def scale_map(bh, j, table_ref, vlen_ref, hkv=hkv):
-        return (table_ref[bh // hkv, j], 0)
+        return (table_ref[bh // hkv, j], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, g, d), lambda bh, j, t, vl: (bh, 0, 0)),
@@ -160,13 +161,15 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     ]
     args = [qf, kf, vf]
     if quant:
+        # (P, 1, page): a (1, page) block spans the array's last two dims,
+        # which the TPU tiling rule accepts for any page; (P, page) with a
+        # (1, page) block would put 1 on the sublane dim
         in_specs += [
-            pl.BlockSpec((1, page),
+            pl.BlockSpec((1, 1, page),
                          lambda bh, j, t, vl: scale_map(bh, j, t, vl)),
-            pl.BlockSpec((1, page),
-                         lambda bh, j, t, vl: scale_map(bh, j, t, vl)),
-        ]
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        ] * 2
+        args += [sc.astype(jnp.float32).reshape(pool, 1, page)
+                 for sc in (k_scale, v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -1,7 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init).  Everything below is ordinary.
+os.environ["JAX_PLATFORMS"] = "cpu"  # fake devices only: never hold a chip
+# The lines above MUST run before any other import (jax locks the platform
+# and device count at first init).  Everything below is ordinary.
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and extract memory/cost/collective evidence.

@@ -26,7 +26,8 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, smoke_config
-from repro.models import RuntimeFlags, build
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import ModelBundle, RuntimeFlags, build
 from repro.serve import (DisaggConfig, DisaggPool, Request, ServeEngine,
                          ServeStats, aggregate_stats)
 from repro.train import CheckpointManager
@@ -35,6 +36,42 @@ from repro.train import CheckpointManager
 # examples/serve_lm.py)
 _PRIORITY_MIX = {"off": lambda i: 0, "low": lambda i: 0,
                  "high": lambda i: 1, "mixed": lambda i: i % 2}
+
+
+def build_bundle(arch: str, *, smoke: bool = False,
+                 kv_int8: bool = False) -> ModelBundle:
+    """The served model: ``arch`` at its published widths (or its reduced
+    same-family ``smoke`` config) with the serving runtime flags."""
+    cfg = smoke_config(ARCHS[arch]) if smoke else ARCHS[arch]
+    flags = RuntimeFlags(attn_impl="chunked", attn_bq=64, attn_bkv=64,
+                         moe_impl="dense", loss_chunk=64,
+                         kv_dtype="int8" if kv_int8 else "native")
+    return build(cfg, flags)
+
+
+def init_params(bundle: ModelBundle, seed: int = 0, dist=None):
+    """Weights drawn from ``seed`` by ONE jitted program: the float32 draws
+    fuse into their casts instead of materializing op by op, and under TP
+    (``dist``, a :class:`repro.dist.ServeMesh`) every leaf lands directly in
+    its shard — no leaf is ever whole on one device."""
+    out = None if dist is None else dist.param_shardings(bundle)
+    return jax.jit(bundle.init, out_shardings=out)(jax.random.PRNGKey(seed))
+
+
+def make_requests(vocab_size: int, n: int, *, seed: int = 0,
+                  prompt_len=(4, 24), max_new: int = 16,
+                  priority: str = "off") -> List[Request]:
+    """``n`` requests with prompt lengths drawn from ``[lo, hi)`` and
+    tokens from the vocabulary, all from ``seed``."""
+    rng = np.random.default_rng(seed)
+    mix = _PRIORITY_MIX[priority]
+    reqs = []
+    for i in range(n):
+        prompt = rng.integers(0, vocab_size,
+                              size=int(rng.integers(*prompt_len)))
+        reqs.append(Request(rid=i, prompt=prompt.astype(np.int32),
+                            max_new_tokens=max_new, priority=mix(i)))
+    return reqs
 
 
 def device_groups(tp: int, dp: int,
@@ -104,30 +141,49 @@ class ReplicaPool:
         return aggregate_stats(self.engines)
 
 
-def build_pool(bundle, params, *, tp: int = 1, dp: int = 1,
-               devices: Optional[Sequence] = None,
+def _placed_params(bundle, params, param_seed: int, meshes) -> list:
+    """Each engine's params: ``params`` as given, or (``None``) drawn from
+    ``param_seed`` in place — once per distinct device group, so engines
+    sharing devices share one copy."""
+    if params is not None:
+        return [params] * len(meshes)
+    drawn = {}
+    out = []
+    for m in meshes:
+        key = None if m is None else tuple(d.id for d in m.mesh.devices.flat)
+        if key not in drawn:
+            drawn[key] = init_params(bundle, param_seed, m)
+        out.append(drawn[key])
+    return out
+
+
+def build_pool(bundle, params=None, *, tp: int = 1, dp: int = 1,
+               devices: Optional[Sequence] = None, param_seed: int = 0,
                **engine_kw) -> ReplicaPool:
     """``dp`` engine replicas, each TP-sharded over its own ``tp``-device
     group.  With ``tp * dp == 1`` the single engine runs undistributed
     (no mesh, any backend); any wider layout shards/pins KV page pools,
-    so the paged backend is required."""
+    so the paged backend is required.  ``params=None`` draws the weights
+    from ``param_seed`` directly in each replica's shardings."""
     from repro.dist import ServeMesh
 
     if tp * dp == 1:
-        return ReplicaPool([ServeEngine(bundle, params, **engine_kw)])
-    engine_kw.setdefault("cache_backend", "paged")
-    groups = device_groups(tp, dp, devices)
-    engines = [ServeEngine(bundle, params, **engine_kw,
-                           dist=ServeMesh.tp(tp, devices=g))
-               for g in groups]
-    return ReplicaPool(engines)
+        meshes = [None]
+    else:
+        engine_kw.setdefault("cache_backend", "paged")
+        meshes = [ServeMesh.tp(tp, devices=g)
+                  for g in device_groups(tp, dp, devices)]
+    return ReplicaPool([
+        ServeEngine(bundle, p, **engine_kw, dist=m)
+        for m, p in zip(meshes, _placed_params(bundle, params, param_seed,
+                                               meshes))])
 
 
-def build_disagg_pool(bundle, params, *, tp: int = 1,
+def build_disagg_pool(bundle, params=None, *, tp: int = 1,
                       prefill_replicas: int = 1, decode_replicas: int = 1,
                       devices: Optional[Sequence] = None,
                       disagg_config: Optional[DisaggConfig] = None,
-                      **engine_kw) -> DisaggPool:
+                      param_seed: int = 0, **engine_kw) -> DisaggPool:
     """The ``disagg`` topology: a prefill pool that ships every finished
     prompt's pages to a decode pool as a checksummed transfer buffer
     (:class:`~repro.serve.cluster.DisaggPool`).  Requires the paged
@@ -137,9 +193,8 @@ def build_disagg_pool(bundle, params, *, tp: int = 1,
     on one chip); with ``tp > 1`` each engine gets its own disjoint
     ``tp``-device group when enough devices exist (prefill groups first),
     and otherwise all engines TP-shard over the *same* ``tp`` devices —
-    the hand-off is still a real gather/scatter across meshes."""
-    import jax
-
+    the hand-off is still a real gather/scatter across meshes.
+    ``params=None`` draws the weights from ``param_seed`` in place."""
     from repro.dist import ServeMesh
 
     if prefill_replicas < 1 or decode_replicas < 1:
@@ -148,8 +203,7 @@ def build_disagg_pool(bundle, params, *, tp: int = 1,
     engine_kw.setdefault("cache_backend", "paged")
     n = prefill_replicas + decode_replicas
     if tp == 1:
-        engines = [ServeEngine(bundle, params, **engine_kw)
-                   for _ in range(n)]
+        meshes = [None] * n
     else:
         pool = list(devices) if devices is not None else list(jax.devices())
         if len(pool) >= tp * n:
@@ -159,9 +213,10 @@ def build_disagg_pool(bundle, params, *, tp: int = 1,
                 raise ValueError(f"tp={tp} needs {tp} devices, have "
                                  f"{len(pool)}")
             groups = [pool[:tp]] * n
-        engines = [ServeEngine(bundle, params, **engine_kw,
-                               dist=ServeMesh.tp(tp, devices=g))
-                   for g in groups]
+        meshes = [ServeMesh.tp(tp, devices=g) for g in groups]
+    engines = [ServeEngine(bundle, p, **engine_kw, dist=m)
+               for m, p in zip(meshes, _placed_params(bundle, params,
+                                                      param_seed, meshes))]
     return DisaggPool(engines[:prefill_replicas],
                       engines[prefill_replicas:], config=disagg_config)
 
@@ -208,18 +263,14 @@ def main(argv=None):
                     help="pin the disagg router's per-request decision "
                          "(auto defers to the swap cost model)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    cfg = smoke_config(ARCHS[args.arch]) if args.smoke else ARCHS[args.arch]
-    flags = RuntimeFlags(attn_impl="chunked", attn_bq=64, attn_bkv=64,
-                         moe_impl="dense", loss_chunk=64,
-                         kv_dtype="int8" if args.kv_int8 else "native")
-    bundle = build(cfg, flags)
+    bundle = build_bundle(args.arch, smoke=args.smoke, kv_int8=args.kv_int8)
+    params = None
     if args.ckpt:
         abs_params, _ = bundle.abstract_params()
         params = CheckpointManager(args.ckpt).restore(
             None, dict(params=abs_params))["params"]
-    else:
-        params = bundle.init(jax.random.PRNGKey(0))
 
     engine_kw = dict(batch_size=args.batch, max_len=args.max_len,
                      window=args.window, seed=args.seed)
@@ -235,14 +286,10 @@ def main(argv=None):
             **engine_kw)
     else:
         pool = build_pool(bundle, params, tp=args.tp, dp=args.dp, **engine_kw)
-    rng = np.random.default_rng(args.seed)
-    mix = _PRIORITY_MIX[args.priority]
-    for i in range(args.requests):
-        prompt = rng.integers(0, cfg.vocab_size,
-                              size=int(rng.integers(4, 24))).astype(np.int32)
-        pool.submit(Request(rid=i, prompt=prompt,
-                            max_new_tokens=args.max_new,
-                            priority=mix(i)))
+    for req in make_requests(bundle.cfg.vocab_size, args.requests,
+                             seed=args.seed, max_new=args.max_new,
+                             priority=args.priority):
+        pool.submit(req)
     t0 = time.perf_counter()
     stats = pool.drain() if args.topology == "colocated" else pool.run()
     dt = time.perf_counter() - t0

@@ -17,6 +17,7 @@ import jax
 
 from repro.configs import ARCHS, ShapeCell, override, smoke_config
 from repro.dist import POLICIES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import RuntimeFlags, build
 from repro.optim import AdamWConfig, schedule
 from repro.train import TrainConfig, Trainer, run_with_recovery
@@ -39,6 +40,7 @@ def main(argv=None):
     ap.add_argument("--max-failures", type=int, default=3)
     ap.add_argument("--mesh-model", type=int, default=1)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")

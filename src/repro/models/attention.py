@@ -148,9 +148,8 @@ def tp_shardable(mesh, axis: str, hq: int, hkv: int) -> bool:
 
 
 def _tp_island(mesh, axis, body, args, in_specs, out_spec):
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=out_spec, check_vma=False)
     return fn(*args)
 
 

@@ -302,6 +302,17 @@ class ServeEngine:
             self.num_ring_pages = int(num_ring_pages
                                       or 1 + batch_size * self.ring_slots)
             self.prefill_chunk = max(8, prefill_chunk)
+            # empty pools come from one jitted program; under TP its
+            # outputs land directly in the per-shard slices (same page ids
+            # on every shard, each holding its own kv-heads stripe of every
+            # page), so no pool is ever whole on one device
+            make = functools.partial(
+                bundle.init_paged_cache,
+                self.num_pages if self.has_full else 1, self.page,
+                batch=batch_size, ring_pages=self.num_ring_pages)
+            self._new_pools = jax.jit(make, out_shardings=(
+                None if dist is None
+                else dist.paged_cache_shardings(jax.eval_shape(make))))
             # prefix pages are only reusable when the WHOLE stack reads
             # them: ring layers rotate prefix tokens away and recurrent
             # state is never cached, so sharing is a pure-full-attn move
@@ -432,14 +443,7 @@ class ServeEngine:
                            if self.attn_window is not None else None)
             if self.prefix is not None:
                 self.prefix = PrefixIndex()
-            self.cache = self.bundle.init_paged_cache(
-                self.num_pages if self.has_full else 1, self.page,
-                batch=self.bsz,
-                ring_pages=self.num_ring_pages)
-            if self.dist is not None:
-                # the per-shard pool slice: same page ids on every shard,
-                # each holding its own kv-heads stripe of every page
-                self.cache = self.dist.shard_paged_cache(self.cache)
+            self.cache = self._new_pools()
             self._htable = np.zeros((self.bsz, max(1, self.pages_per_seq)),
                                     np.int32)
             self._hrtable = np.zeros((self.bsz, max(1, self.ring_slots)),

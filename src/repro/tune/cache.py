@@ -1,23 +1,24 @@
-"""Plan cache: derive once, persist to ``runs/tuneplans.json``, reuse.
+"""Plan cache: derive once per process, reuse; persist only on request.
 
 The cache key is ``kernel|shape_sig|dtype|spec_fingerprint``; a calibration
 (or any change to the spec constants) changes the fingerprint, so stale
-plans are never served — they just age out in the file.  Persistence is
-best-effort: an unwritable directory degrades to a process-local memory
-cache (kernels must keep working from read-only checkouts and inside
-traced/jitted code).
+plans are never served.  Derivation is deterministic, so the default cache
+lives in memory: what a run executes follows from the code it ships, never
+from a file left behind by an earlier run.  ``$REPRO_TUNEPLANS`` (or an
+explicit path) asks for a JSON file; persistence is then best-effort — an
+unwritable directory degrades to the memory cache (kernels must keep
+working from read-only checkouts and inside traced/jitted code).
 """
 from __future__ import annotations
 
 import json
 import os
 import threading
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 from repro.core.memmodel import TPUSpec, V5E
 from repro.tune.plan import KernelPlan, derive_plan, plan_key
 
-DEFAULT_PATH = os.path.join("runs", "tuneplans.json")
 ENV_VAR = "REPRO_TUNEPLANS"
 _SCHEMA = 1
 
@@ -29,7 +30,7 @@ class PlanCache:
     ``{"schema_version": 1, "plans": {key: plan_dict}}``.
     """
 
-    def __init__(self, path: Optional[str] = DEFAULT_PATH):
+    def __init__(self, path: Optional[str] = None):
         self.path = path
         self._plans: Dict[str, KernelPlan] = {}
         self._loaded = path is None
@@ -51,13 +52,6 @@ class PlanCache:
 
     def _save(self) -> None:
         if self.path is None:
-            return
-        if (self.path == DEFAULT_PATH
-                and not os.path.isdir(os.path.dirname(self.path))):
-            # default CWD-relative path outside a repo checkout (no runs/
-            # directory): a pure compute call must not scatter files around
-            # the caller's working directory — stay memory-only.  Explicit
-            # paths ($REPRO_TUNEPLANS / constructor) still create dirs.
             return
         try:
             os.makedirs(os.path.dirname(self.path) or ".", exist_ok=True)
@@ -128,11 +122,12 @@ _default_lock = threading.Lock()
 
 
 def default_cache() -> PlanCache:
-    """Lazy singleton over ``$REPRO_TUNEPLANS`` or ``runs/tuneplans.json``."""
+    """Lazy singleton: a file cache at ``$REPRO_TUNEPLANS`` when set,
+    otherwise memory-only."""
     global _default
     with _default_lock:
         if _default is None:
-            _default = PlanCache(os.environ.get(ENV_VAR, DEFAULT_PATH))
+            _default = PlanCache(os.environ.get(ENV_VAR) or None)
         return _default
 
 

@@ -45,7 +45,7 @@ def spec_fingerprint(spec: TPUSpec) -> str:
     invalidation rule: new constants => new key => plans re-derived.
     """
     raw = (f"{spec.name}|{spec.hbm_bw:.6g}|{spec.dma_latency_s:.6g}"
-           f"|{spec.vmem_bytes}|{spec.clock_hz:.6g}")
+           f"|{spec.vmem_bytes}|{spec.scoped_vmem_bytes}|{spec.clock_hz:.6g}")
     return hashlib.sha1(raw.encode()).hexdigest()[:12]
 
 
@@ -162,6 +162,23 @@ def _shrink_to_budget(bq: int, bkv: int, head_dim: int, db: int,
     return max(8, bq), max(8, bkv)
 
 
+def _flash_scoped_vmem(bq: int, bkv: int, head_dim: int, db: int) -> int:
+    """Scoped VMEM the flash kernel holds: double-buffered q/k/v/out
+    blocks, the f32 scratch (the (bq, 1) m/l rows pad to 128 lanes), f32
+    copies of the tiles, and the (bq, bkv) f32 scores and probabilities.
+    Checked against the TPU compiler: (1024, 1024) at head_dim 128 fits
+    the 16 MiB v5e limit, (2048, 2048) does not."""
+    blocks = 2 * (2 * bq + 2 * bkv) * head_dim * db
+    scratch = bq * (2 * 128 + head_dim) * 4
+    return blocks + scratch + (bq + 2 * bkv) * head_dim * 4 + 2 * bq * bkv * 4
+
+
+def _matmul_scoped_vmem(tile: int, db: int) -> int:
+    """Scoped VMEM of the tiled matmul at a square tile: double-buffered
+    lhs/rhs/out tiles, the f32 accumulator and the f32 dot result."""
+    return tile * tile * (6 * db + 8)
+
+
 def derive_attention_plan(*, sq: int, skv: int, head_dim: int,
                           dtype: str = "bfloat16",
                           kernel: str = "flash_attention",
@@ -175,8 +192,14 @@ def derive_attention_plan(*, sq: int, skv: int, head_dim: int,
     bq, bkv = tune_attention_blocks(head_dim, dtype_bytes=db, spec=spec,
                                     vmem_budget_fraction=vmem_budget_fraction)
     bq, bkv = min(bq, max(8, sq)), min(bkv, max(8, skv))
-    bq, bkv = _shrink_to_budget(bq, bkv, head_dim, db,
-                                spec.vmem_bytes * vmem_budget_fraction, 2)
+    # the tuner budgets the physical buffer; the kernel gets the scoped
+    # limit, so halve the larger tile until its real footprint fits that
+    while (_flash_scoped_vmem(bq, bkv, head_dim, db) > spec.scoped_vmem_bytes
+           and max(bq, bkv) > 8):
+        if bkv >= bq:
+            bkv //= 2
+        else:
+            bq //= 2
     knobs = Knobs(unit_bytes=head_dim * db, burst_bytes=bkv * head_dim * db,
                   outstanding=2)
     return KernelPlan(
@@ -269,17 +292,16 @@ def derive_verify_plan(*, verify_tokens: int, max_len: int, head_dim: int,
 
 
 def derive_matmul_plan(*, m: int, n: int, k: int, dtype: str = "bfloat16",
-                       spec: Optional[TPUSpec] = None, calibration=None,
-                       vmem_budget_fraction: float = 0.4) -> KernelPlan:
+                       spec: Optional[TPUSpec] = None,
+                       calibration=None) -> KernelPlan:
     """Square tile for the tiled matmul: the largest MXU-aligned tile whose
-    triple (lhs, rhs, acc) double-buffered footprint fits the budget."""
+    whole footprint fits the kernel's scoped VMEM."""
     import jax.numpy as jnp
     spec, source = _resolve_spec(spec, calibration)
     db = jnp.dtype(dtype).itemsize
-    budget = spec.vmem_bytes * vmem_budget_fraction
     tile = 128
     for t in (128, 256, 512, 1024):
-        if 2 * (2 * t * t * db + t * t * 4) <= budget:
+        if _matmul_scoped_vmem(t, db) <= spec.scoped_vmem_bytes:
             tile = t
     tile = min(tile, max(8, m), max(8, n), max(8, k))
     knobs = Knobs(unit_bytes=tile * db, burst_bytes=tile * tile * db,

@@ -190,6 +190,21 @@ def test_default_cache_swap_and_env(tmp_path, monkeypatch):
         set_default_cache(None)
 
 
+def test_default_cache_is_memory_only_without_env(tmp_path, monkeypatch):
+    """No file is read or written unless a path is asked for."""
+    monkeypatch.delenv("REPRO_TUNEPLANS", raising=False)
+    monkeypatch.chdir(tmp_path)
+    (tmp_path / "runs").mkdir()
+    set_default_cache(None)
+    try:
+        assert default_cache().path is None
+        plan_for("matmul", shape_sig=(256, 256, 256), dtype="float32")
+        assert len(default_cache()) == 1
+        assert not any(tmp_path.rglob("*.json"))
+    finally:
+        set_default_cache(None)
+
+
 def test_plan_defaults_reach_the_kernels(tmp_path, monkeypatch):
     """tentpole: kernels called with no blocks use the cached plan and still
     match the oracle (the applied-knobs path is correct end to end)."""

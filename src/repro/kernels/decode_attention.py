@@ -99,6 +99,7 @@ def _decode_call(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, g, d), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(valid_len.astype(jnp.int32), qf, kf, vf)
     return out.reshape(b, hq, d)
 

@@ -121,6 +121,7 @@ def _flash_call(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, hq, sq_p, d)[:, :, :sq]
 

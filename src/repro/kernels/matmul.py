@@ -52,6 +52,7 @@ def _matmul_call(x: jax.Array, y: jax.Array, *, bm: int, bn: int,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="matmul",
     )(x, y)
 
 

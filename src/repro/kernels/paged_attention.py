@@ -189,5 +189,6 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, g, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table.astype(jnp.int32), valid_len.astype(jnp.int32), *args)
     return out.reshape(b, hq, d)

@@ -46,6 +46,7 @@ from typing import Deque, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.engine import Request, ServeEngine, ServeStats
 from repro.serve.hosttier import HostKVEntry
@@ -404,35 +405,38 @@ class ClusterFrontEnd:
 
     # -- latency accounting ---------------------------------------------
     def _harvest(self) -> None:
-        for rid in list(self._live):
-            req = self._live[rid]
-            lat = self._lat[rid]
-            if lat.first is None and req.out_tokens:
-                lat.first = self.round
-            if req.done:
-                lat.finish = self.round
-                lat.tokens = len(req.out_tokens)
-                self.cstats.completed += 1
-                del self._live[rid]
+        with TraceAnnotation("serve.harvest"):
+            for rid in list(self._live):
+                req = self._live[rid]
+                lat = self._lat[rid]
+                if lat.first is None and req.out_tokens:
+                    lat.first = self.round
+                if req.done:
+                    lat.finish = self.round
+                    lat.tokens = len(req.out_tokens)
+                    self.cstats.completed += 1
+                    del self._live[rid]
 
     # ------------------------------------------------------------------
     def step(self, arrivals: Optional[Deque[Tuple[int, Request]]] = None
              ) -> bool:
         """One virtual-clock round.  Returns False once fully drained."""
-        if arrivals is not None:
-            while arrivals and arrivals[0][0] <= self.round:
-                self.submit(arrivals.popleft()[1])
-        self._probe_round()
-        self._route_round()
-        for rep in self.replicas:
-            if rep.state != QUARANTINED:
-                rep.step_round()
-        self._harvest()
-        for rep in self.replicas:
-            rep.tick_faults()
-        self.round += 1
-        self.cstats.rounds = self.round
-        return bool(self.backlog or self._live or arrivals)
+        with TraceAnnotation("serve.step"):
+            if arrivals is not None:
+                while arrivals and arrivals[0][0] <= self.round:
+                    self.submit(arrivals.popleft()[1])
+            with TraceAnnotation("serve.route"):
+                self._probe_round()
+                self._route_round()
+            for rep in self.replicas:
+                if rep.state != QUARANTINED:
+                    rep.step_round()
+            self._harvest()
+            for rep in self.replicas:
+                rep.tick_faults()
+            self.round += 1
+            self.cstats.rounds = self.round
+            return bool(self.backlog or self._live or arrivals)
 
     def run(self, schedule: Sequence[Tuple[int, Request]] = (),
             chaos=None, max_rounds: int = 100_000) -> ServeStats:

@@ -29,6 +29,11 @@ from the advisor's ``unit_bytes >= 512B`` transaction-optimum rule, so
 calibration reshapes the pool exactly the way it reshapes attention
 blocks.
 
+Each layer boundary of a round records a ``serve.*`` host span
+(``jax.profiler.TraceAnnotation``: inert until a profiler session opens),
+and each jitted program has a name of its own (``jit_decode_window``), so a
+profile puts the host's work and the device's on one clock.
+
 Speculative decoding (``draft_bundle``) rides the paged fast path: a
 small draft model proposes ``spec_k`` tokens per dispatch from a dense
 per-slot cache, the target verifies all of them in ONE batched
@@ -58,6 +63,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ATTN
 from repro.core.memmodel import next_pow2
@@ -306,8 +312,8 @@ class ServeEngine:
             # outputs land directly in the per-shard slices (same page ids
             # on every shard, each holding its own kv-heads stripe of every
             # page), so no pool is ever whole on one device
-            make = functools.partial(
-                bundle.init_paged_cache,
+            make = _named(
+                "new_page_pools", bundle.init_paged_cache,
                 self.num_pages if self.has_full else 1, self.page,
                 batch=batch_size, ring_pages=self.num_ring_pages)
             self._new_pools = jax.jit(make, out_shardings=(
@@ -327,15 +333,17 @@ class ServeEngine:
 
             self._paged_prefill = jax.jit(_prefill_impl, donate_argnums=(1,))
             self._paged_decode_many = jax.jit(
-                functools.partial(_paged_decode_many_impl, bundle, self.plan,
-                                  self.sampling),
+                _named("decode_window", _paged_decode_many_impl, bundle,
+                       self.plan, self.sampling),
                 static_argnums=(0,), donate_argnums=(2,))
         else:
-            self._prefill = jax.jit(
-                lambda p, toks, vl: bundle.prefill(
-                    p, dict(tokens=toks, valid_len=vl)))
+            def prefill(p, toks, vl):
+                return bundle.prefill(p, dict(tokens=toks, valid_len=vl))
+
+            self._prefill = jax.jit(prefill)
             self._decode_many = jax.jit(
-                functools.partial(_decode_many_impl, bundle, self.sampling),
+                _named("decode_window", _decode_many_impl, bundle,
+                       self.sampling),
                 static_argnums=(0,), donate_argnums=(2,))
         if draft_bundle is not None:
             self._init_spec(draft_bundle)
@@ -400,12 +408,13 @@ class ServeEngine:
         # page_size override must reach the verify plan too
         self.vplan = (vplan if vplan.page_size == self.page
                       else dataclasses.replace(vplan, bkv=self.page))
-        self._draft_prefill = jax.jit(
-            lambda p, toks, vl: self.draft.prefill(
-                p, dict(tokens=toks, valid_len=vl)))
+        def draft_prefill(p, toks, vl):
+            return self.draft.prefill(p, dict(tokens=toks, valid_len=vl))
+
+        self._draft_prefill = jax.jit(draft_prefill)
         self._spec_decode = jax.jit(
-            functools.partial(_spec_decode_many_impl, self.bundle, self.draft,
-                              self.vplan, self.sampling, self.spec_k),
+            _named("spec_decode_window", _spec_decode_many_impl, self.bundle,
+                   self.draft, self.vplan, self.sampling, self.spec_k),
             donate_argnums=(2, 3))
 
     def _init_state(self) -> None:
@@ -1097,70 +1106,74 @@ class ServeEngine:
         run_to_completion interleaves these with decode windows, so a long
         prompt admits without stalling in-flight decodes."""
         req = self.slots[slot]
-        res = self._resume.get(req.rid)
-        prompt = req.prompt if res is None else res.ctx
-        s = int(prompt.shape[0])
-        off = self._pending[slot]
-        c = min(self.prefill_chunk, s - off)
-        cb = (min(next_pow2(max(8, c)), self.prefill_chunk)
-              if self.bucket_prompts else c)
-        if ("chunk", cb) not in self._seen_prefill_shapes:
-            self._seen_prefill_shapes.add(("chunk", cb))
-            self.stats.prefill_retraces += 1
-        chunk = np.zeros((1, cb), np.int32)
-        chunk[0, :c] = prompt[off:off + c]
-        row = self.alloc.tables[req.rid] if self.alloc is not None else []
-        trow = np.zeros((1, max(1, self.pages_per_seq)), np.int32)
-        trow[0, :len(row)] = row
-        rrow = np.zeros((1, max(1, self.ring_slots)), np.int32)
-        if self.ralloc is not None:
-            rring = self.ralloc.tables[req.rid]
-            rrow[0, :len(rring)] = rring
-        self.cache, logits = self._paged_prefill(
-            self.params, self.cache, jnp.asarray(chunk),
-            jnp.asarray([off], jnp.int32),
-            dict(full=jnp.asarray(trow), ring=jnp.asarray(rrow)),
-            jnp.asarray([c], jnp.int32), jnp.int32(slot))
-        self.stats.prefill_chunks += 1
-        self._chunks_since_decode += 1
-        off += c
-        if off < s:
-            self._pending[slot] = off
-            return
-        # prompt complete: seed decoding and publish the table rows
-        del self._pending[slot]
-        if self.prefix is not None:
-            for i, h in enumerate(self._hashes.get(req.rid, [])):
-                if self.prefix.register(h, row[i]):
-                    self.alloc.pin(row[i])
-        self._hashes.pop(req.rid, None)
-        self._htable[slot, :] = 0
-        self._htable[slot, :len(row)] = row
-        if self.ralloc is not None:
-            rring = self.ralloc.tables[req.rid]
-            self._hrtable[slot, :] = 0
-            self._hrtable[slot, :len(rring)] = rring
-        self._table_dirty = True
-        self.pos = self.pos.at[slot].set(s)
-        self._hpos[slot] = s
-        if res is None:
-            self._assign_key(slot, req)
-            tok0 = self._seed_token(slot, np.asarray(logits)[0])
-            req.out_tokens.append(tok0)
-            self.stats.tokens_out += 1
-        else:
-            # recompute-resume: the context's last logits re-derive a token
-            # that was already emitted — re-feed it, never re-sample, and
-            # fast-forward the PRNG chain to where the preempted run stood
-            self._resume.pop(req.rid)
-            self._replay_key(slot, req)
-            tok0 = int(res.pending)
-            self.stats.recompute_resumes += 1
-        self.tokens = self.tokens.at[slot, 0].set(tok0)
-        if self.draft is not None:
-            self._draft_prefill_slot(slot, req,
-                                     tokens=None if res is None else res.ctx)
-        self.stats.prefills += 1
+        with TraceAnnotation("serve.prefill_chunk", rid=req.rid):
+            res = self._resume.get(req.rid)
+            prompt = req.prompt if res is None else res.ctx
+            s = int(prompt.shape[0])
+            off = self._pending[slot]
+            c = min(self.prefill_chunk, s - off)
+            cb = (min(next_pow2(max(8, c)), self.prefill_chunk)
+                  if self.bucket_prompts else c)
+            if ("chunk", cb) not in self._seen_prefill_shapes:
+                self._seen_prefill_shapes.add(("chunk", cb))
+                self.stats.prefill_retraces += 1
+            chunk = np.zeros((1, cb), np.int32)
+            chunk[0, :c] = prompt[off:off + c]
+            row = self.alloc.tables[req.rid] if self.alloc is not None else []
+            trow = np.zeros((1, max(1, self.pages_per_seq)), np.int32)
+            trow[0, :len(row)] = row
+            rrow = np.zeros((1, max(1, self.ring_slots)), np.int32)
+            if self.ralloc is not None:
+                rring = self.ralloc.tables[req.rid]
+                rrow[0, :len(rring)] = rring
+            self.cache, logits = self._paged_prefill(
+                self.params, self.cache, jnp.asarray(chunk),
+                jnp.asarray([off], jnp.int32),
+                dict(full=jnp.asarray(trow), ring=jnp.asarray(rrow)),
+                jnp.asarray([c], jnp.int32), jnp.int32(slot))
+            self.stats.prefill_chunks += 1
+            self._chunks_since_decode += 1
+            off += c
+            if off < s:
+                self._pending[slot] = off
+                return
+            # prompt complete: seed decoding and publish the table rows
+            del self._pending[slot]
+            if self.prefix is not None:
+                for i, h in enumerate(self._hashes.get(req.rid, [])):
+                    if self.prefix.register(h, row[i]):
+                        self.alloc.pin(row[i])
+            self._hashes.pop(req.rid, None)
+            self._htable[slot, :] = 0
+            self._htable[slot, :len(row)] = row
+            if self.ralloc is not None:
+                rring = self.ralloc.tables[req.rid]
+                self._hrtable[slot, :] = 0
+                self._hrtable[slot, :len(rring)] = rring
+            self._table_dirty = True
+            self.pos = self.pos.at[slot].set(s)
+            self._hpos[slot] = s
+            if res is None:
+                self._assign_key(slot, req)
+                with TraceAnnotation("serve.device_wait"):
+                    last = np.asarray(logits)[0]
+                tok0 = self._seed_token(slot, last)
+                req.out_tokens.append(tok0)
+                self.stats.tokens_out += 1
+            else:
+                # recompute-resume: the context's last logits re-derive a
+                # token that was already emitted — re-feed it, never
+                # re-sample, and fast-forward the PRNG chain to where the
+                # preempted run stood
+                self._resume.pop(req.rid)
+                self._replay_key(slot, req)
+                tok0 = int(res.pending)
+                self.stats.recompute_resumes += 1
+            self.tokens = self.tokens.at[slot, 0].set(tok0)
+            if self.draft is not None:
+                self._draft_prefill_slot(
+                    slot, req, tokens=None if res is None else res.ctx)
+            self.stats.prefills += 1
 
     def _draft_prefill_slot(self, slot: int, req: Request,
                             tokens: Optional[np.ndarray] = None) -> None:
@@ -1185,40 +1198,42 @@ class ServeEngine:
             self.draft_cache, dcache1, slot)
 
     def _admit(self) -> None:
-        while self.queue:
-            if len(self.queue) > 1:
-                self.sched.order_queue(self.queue, self._arrival)
-            req = self.queue[0]
-            slot = self._free_slot()
-            if slot is None:
-                # no slot: a strictly-lower-priority victim yields its seat
-                # (uniform priorities — the default — never preempt here)
-                victim = self._pick_victim(below=req.priority)
-                if victim is None:
-                    break
-                self.preempt(victim)
-                continue
-            if self.backend == "paged":
-                try:
-                    self._paged_admit_slot(slot, req)
-                except PoolExhausted:
+        with TraceAnnotation("serve.admit"):
+            while self.queue:
+                if len(self.queue) > 1:
+                    self.sched.order_queue(self.queue, self._arrival)
+                req = self.queue[0]
+                slot = self._free_slot()
+                if slot is None:
+                    # no slot: a strictly-lower-priority victim yields its
+                    # seat (uniform priorities — the default — never preempt
+                    # here)
                     victim = self._pick_victim(below=req.priority)
                     if victim is None:
-                        # backpressure: the request stays queued; pages
-                        # free as in-flight requests finish
-                        self.stats.pool_stalls += 1
                         break
                     self.preempt(victim)
                     continue
-                self.queue.pop(0)
-            else:
-                self.queue.pop(0)
-                self._prefill_into_slot(slot, req)
-        if self.backend == "paged":
-            for slot in self.sched.prefill_order(
-                    list(self._pending),
-                    lambda i: self.slots[i].priority):
-                self._prefill_tick(slot)
+                if self.backend == "paged":
+                    try:
+                        self._paged_admit_slot(slot, req)
+                    except PoolExhausted:
+                        victim = self._pick_victim(below=req.priority)
+                        if victim is None:
+                            # backpressure: the request stays queued; pages
+                            # free as in-flight requests finish
+                            self.stats.pool_stalls += 1
+                            break
+                        self.preempt(victim)
+                        continue
+                    self.queue.pop(0)
+                else:
+                    self.queue.pop(0)
+                    self._prefill_into_slot(slot, req)
+            if self.backend == "paged":
+                for slot in self.sched.prefill_order(
+                        list(self._pending),
+                        lambda i: self.slots[i].priority):
+                    self._prefill_tick(slot)
 
     # ------------------------------------------------------------------
     def _budgets(self, n: int) -> np.ndarray:
@@ -1285,8 +1300,55 @@ class ServeEngine:
         model attached the dispatch is one speculative draft->verify round
         instead, emitting up to ``spec_k + 1`` tokens per slot.  Returns
         the number of real tokens produced."""
-        if self.draft is not None:
-            n = min(n, self.spec_k + 1)
+        with TraceAnnotation("serve.decode"):
+            if self.draft is not None:
+                n = min(n, self.spec_k + 1)
+            with TraceAnnotation("serve.reserve"):
+                budgets = self._window_budgets(n)
+            if budgets is None:
+                return 0
+            self.stats.prefill_burst_max = max(self.stats.prefill_burst_max,
+                                               self._chunks_since_decode)
+            self._chunks_since_decode = 0
+            if self.draft is not None:
+                return self._spec_dispatch(budgets)
+            n_run = min(n, next_pow2(int(budgets.max())))  # pow2: few traces
+            with TraceAnnotation("serve.dispatch"):
+                steps = jnp.asarray(np.minimum(budgets, n_run), jnp.int32)
+                if self.backend == "paged":
+                    (self.cache, self.tokens, self.pos, self.keys,
+                     out) = self._paged_decode_many(
+                        n_run, self.params, self.cache, self.tokens, self.pos,
+                        steps, self.keys, self._table)
+                else:
+                    (self.cache, self.tokens, self.pos, self.keys,
+                     out) = self._decode_many(
+                        n_run, self.params, self.cache, self.tokens, self.pos,
+                        steps, self.keys)
+            self.stats.decode_steps += n_run
+            self.stats.decode_dispatches += 1
+
+            with TraceAnnotation("serve.device_wait"):
+                out_np = np.asarray(out)  # (n_run, B) — the one host sync
+            with TraceAnnotation("serve.unpack"):
+                produced = 0
+                for i, req in enumerate(self.slots):
+                    if req is None or (self.backend == "paged"
+                                       and i in self._pending):
+                        continue
+                    adv = int(min(budgets[i], n_run))
+                    req.out_tokens.extend(int(t) for t in out_np[:adv, i])
+                    self._hpos[i] += adv
+                    produced += adv
+                    if req.done or self._hpos[i] >= self.max_len - 1:
+                        self._release_finished(i)
+                self.stats.tokens_out += produced
+            return produced
+
+    def _window_budgets(self, n: int) -> Optional[np.ndarray]:
+        """Each slot's token budget for an ``n``-tick window, with the pages
+        it needs reserved and the device page table in sync.  Slots that can
+        never advance are retired first.  None when no slot can advance."""
         budgets = self._budgets(n)
         blocked = (self._reserve_window_pages(budgets)
                    if self.backend == "paged"
@@ -1337,42 +1399,10 @@ class ServeEngine:
                                    + (self.num_ring_pages if self.ralloc
                                       else 0)),
                         live_pages=in_use, free_pages=free)
-                return 0
-        self.stats.prefill_burst_max = max(self.stats.prefill_burst_max,
-                                           self._chunks_since_decode)
-        self._chunks_since_decode = 0
-        if self.draft is not None:
-            return self._spec_dispatch(budgets)
-        n_run = min(n, next_pow2(top))  # pow2 ticks: bounded trace count
-        steps = jnp.asarray(np.minimum(budgets, n_run), jnp.int32)
-        if self.backend == "paged":
-            if self._table_dirty:
-                self._sync_table()
-            (self.cache, self.tokens, self.pos, self.keys,
-             out) = self._paged_decode_many(
-                n_run, self.params, self.cache, self.tokens, self.pos, steps,
-                self.keys, self._table)
-        else:
-            (self.cache, self.tokens, self.pos, self.keys,
-             out) = self._decode_many(
-                n_run, self.params, self.cache, self.tokens, self.pos, steps,
-                self.keys)
-        self.stats.decode_steps += n_run
-        self.stats.decode_dispatches += 1
-
-        out_np = np.asarray(out)  # (n_run, B) — the one host sync
-        produced = 0
-        for i, req in enumerate(self.slots):
-            if req is None or (self.backend == "paged" and i in self._pending):
-                continue
-            adv = int(min(budgets[i], n_run))
-            req.out_tokens.extend(int(t) for t in out_np[:adv, i])
-            self._hpos[i] += adv
-            produced += adv
-            if req.done or self._hpos[i] >= self.max_len - 1:
-                self._release_finished(i)
-        self.stats.tokens_out += produced
-        return produced
+                return None
+        if self.backend == "paged" and self._table_dirty:
+            self._sync_table()
+        return budgets
 
     def _spec_dispatch(self, budgets: np.ndarray) -> int:
         """One speculative draft->verify round in a single fused dispatch.
@@ -1386,37 +1416,38 @@ class ServeEngine:
         back to its accepted length: pages covering only rejected suffix
         rows return to the pool (shared prefix pages are refcounted, never
         mutated)."""
-        if self._table_dirty:
-            self._sync_table()
-        steps = jnp.asarray(budgets, jnp.int32)
-        (self.cache, self.draft_cache, self.tokens, self.pos, self.keys,
-         out, meta) = self._spec_decode(
-            self.params, self.draft_params, self.cache, self.draft_cache,
-            self.tokens, self.pos, steps, self.keys, self._table)
+        with TraceAnnotation("serve.dispatch"):
+            steps = jnp.asarray(budgets, jnp.int32)
+            (self.cache, self.draft_cache, self.tokens, self.pos, self.keys,
+             out, meta) = self._spec_decode(
+                self.params, self.draft_params, self.cache, self.draft_cache,
+                self.tokens, self.pos, steps, self.keys, self._table)
         # one spec round always advances every unblocked slot >= 1 token,
         # so a "tick" for progress accounting is one dispatch
         self.stats.decode_steps += 1
         self.stats.decode_dispatches += 1
         self.stats.spec_steps += 1
 
-        out_np = np.asarray(out)    # (B, k+1) — the one host sync
-        meta_np = np.asarray(meta)  # (3, B): emitted / accepted / proposed
-        produced = 0
-        for i, req in enumerate(self.slots):
-            if req is None or i in self._pending or budgets[i] == 0:
-                continue
-            adv = int(meta_np[0, i])
-            req.out_tokens.extend(int(t) for t in out_np[i, :adv])
-            self._hpos[i] += adv
-            produced += adv
-            self.stats.draft_tokens += int(meta_np[2, i])
-            self.stats.draft_accepted += int(meta_np[1, i])
-            # rejected-suffix rollback: the window reservation ran ahead to
-            # hpos + budget; shrink it to what was actually emitted
-            self.alloc.truncate(req.rid, int(self._hpos[i]))
-            if req.done or self._hpos[i] >= self.max_len - 1:
-                self._release_finished(i)
-        self.stats.tokens_out += produced
+        with TraceAnnotation("serve.device_wait"):
+            out_np = np.asarray(out)    # (B, k+1) — the one host sync
+            meta_np = np.asarray(meta)  # (3, B): emitted / accepted / proposed
+        with TraceAnnotation("serve.unpack"):
+            produced = 0
+            for i, req in enumerate(self.slots):
+                if req is None or i in self._pending or budgets[i] == 0:
+                    continue
+                adv = int(meta_np[0, i])
+                req.out_tokens.extend(int(t) for t in out_np[i, :adv])
+                self._hpos[i] += adv
+                produced += adv
+                self.stats.draft_tokens += int(meta_np[2, i])
+                self.stats.draft_accepted += int(meta_np[1, i])
+                # rejected-suffix rollback: the window reservation ran ahead
+                # to hpos + budget; shrink it to what was actually emitted
+                self.alloc.truncate(req.rid, int(self._hpos[i]))
+                if req.done or self._hpos[i] >= self.max_len - 1:
+                    self._release_finished(i)
+            self.stats.tokens_out += produced
         return produced
 
     def _release_finished(self, i: int) -> None:
@@ -1459,6 +1490,15 @@ class ServeEngine:
             # zero-budget slots (pool-blocked slots wait on those releases)
             self.decode_many(self.window)
         return self.stats
+
+
+def _named(name: str, fn, *args, **kwargs):
+    """``functools.partial(fn, *args, **kwargs)`` called ``name``: ``jax.jit``
+    names the program's module ``jit_<name>``, which is how a profile finds
+    it (an unnamed partial compiles to ``jit__unknown``)."""
+    part = functools.partial(fn, *args, **kwargs)
+    part.__name__ = name
+    return part
 
 
 def _gather_pages_impl(cache, pids):

@@ -13,6 +13,7 @@ The topology is described inside a fixture (never at import: only one
 process may load the TPU library, and test workers import every file).
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +87,28 @@ def test_paged_attention_compiles(one_chip, variant):
             paged_attention, plan=plan, interpret=False, window=window,
             softcap=50.0 if window else None)
     _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("wrapped", [True, False], ids=["jit", "unwrapped"])
+def test_paged_attention_keeps_its_name(one_chip, wrapped):
+    """In a caller's program of another name, with or without the kernel's
+    own ``jax.jit`` around it, the kernel's custom call is
+    ``paged_attention.N``: the name a trace reduction finds it by."""
+    plan, page, slots, pool = _paged_shapes(jnp.bfloat16)
+    kernel = paged_attention if wrapped else paged_attention.__wrapped__
+
+    def decode_window(q, kp, vp, t, vl):
+        return kernel(q, kp, vp, t, vl, plan=plan, interpret=False)
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((BATCH, HQ, D), jnp.bfloat16), ((pool, page, HKV, D), jnp.bfloat16),
+        ((pool, page, HKV, D), jnp.bfloat16), ((BATCH, slots), jnp.int32),
+        ((BATCH,), jnp.int32))]
+    text = jax.jit(decode_window).lower(*args).compile().as_text()
+    calls = re.findall(r"%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    assert calls
+    assert all(re.fullmatch(r"paged_attention(\.\d+)*", c) for c in calls)
 
 
 def test_decode_attention_compiles(one_chip):

@@ -133,7 +133,7 @@ def paged_gather_attention(q, k_pages, v_pages, page_table, p: AttnParams,
 # walks ITS OWN slice of the pools — every device streams pages from its
 # own HBM stack, so aggregate KV bandwidth scales with the axis size.
 # Placement is explicit (shard_map, not GSPMD inference) because the Pallas
-# kernel's BlockSpec index_map dereferences the table: the partitioner
+# kernel dereferences the table to copy pages itself: the partitioner
 # cannot see that page ids are head-invariant, so left to itself it would
 # all-gather the pools.  GQA stays shard-local: with tp dividing both Hq
 # and Hkv, contiguous head blocks keep every query group and its kv head on

@@ -183,7 +183,7 @@ def _ring_gather(cache, tbl, off, page, window, dtype):
 
 
 def _paged_attn(q, k, v, cache, ap, spec, pos, table, chunk_valid, cfg,
-                flags, mode, plan=None):
+                flags, mode, plan=None, active=None):
     """The paged-cache mixer body (both paged modes).
 
     Full-attention layers read ``table["full"]`` (logical page j at absolute
@@ -194,7 +194,9 @@ def _paged_attn(q, k, v, cache, ap, spec, pos, table, chunk_valid, cfg,
     int8-dequant paths included); extend (prefill chunks) attends over a
     gathered view — ring layers attend *before* writing, because a chunk
     crossing a page boundary rotates the trailing page that its own early
-    queries still need.  ``kv_dtype="int8"`` quantizes per token before the
+    queries still need.  A decode slot that ``active`` (B,) marks False
+    (retired, pending prefill, budget spent) reads no pages: its output is
+    discarded.  ``kv_dtype="int8"`` quantizes per token before the
     scatter and stores the scales in per-page lanes.  Pad positions
     (bucketed chunks, masked decode ticks on retired slots) are steered to
     page 0 — the engine reserves it as a null page, so masked writes can
@@ -267,16 +269,19 @@ def _paged_attn(q, k, v, cache, ap, spec, pos, table, chunk_valid, cfg,
     tp = attn_mod.tp_shardable(flags.mesh, flags.tp_axis,
                                q.shape[2], kp.shape[2])
     if mode == "paged_decode":  # S == 1: the kernel's regime
+        kvalid = posv + 1
+        if active is not None:
+            kvalid = jnp.where(active, kvalid, 0)
         if tp:
             o = attn_mod.tp_paged_attention(
-                flags.mesh, flags.tp_axis, q[:, 0], kp, vp, tbl, posv + 1,
+                flags.mesh, flags.tp_axis, q[:, 0], kp, vp, tbl, kvalid,
                 scale=ap.scale, softcap=ap.softcap,
                 window=spec.sliding_window,
                 k_scale=new_cache.get("k_scale"),
                 v_scale=new_cache.get("v_scale"), plan=plan)[:, None]
         else:
             o = kops.paged_attention(
-                q[:, 0], kp, vp, tbl, posv + 1, scale=ap.scale,
+                q[:, 0], kp, vp, tbl, kvalid, scale=ap.scale,
                 softcap=ap.softcap, window=spec.sliding_window,
                 k_scale=new_cache.get("k_scale"),
                 v_scale=new_cache.get("v_scale"), plan=plan)[:, None]
@@ -296,7 +301,7 @@ def _paged_attn(q, k, v, cache, ap, spec, pos, table, chunk_valid, cfg,
 
 
 def _apply_attn(p, x, cfg, spec, flags, mode, cache, pos, table=None,
-                chunk_valid=None, plan=None):
+                chunk_valid=None, plan=None, active=None):
     bsz, s, d = x.shape
     hd = cfg.resolved_head_dim
     shd = flags.shd
@@ -307,7 +312,8 @@ def _apply_attn(p, x, cfg, spec, flags, mode, cache, pos, table=None,
 
     if mode in ("paged_decode", "paged_extend"):
         o, new_cache = _paged_attn(q, k, v, cache, ap, spec, pos, table,
-                                   chunk_valid, cfg, flags, mode, plan)
+                                   chunk_valid, cfg, flags, mode, plan,
+                                   active)
     elif mode == "decode":
         # scalar pos (batch-uniform decode, the dry-run/throughput path) uses
         # dynamic-update-slice — SPMD-friendly on seq-sharded caches; vector
@@ -444,7 +450,8 @@ def _apply_layer(p, x, cfg, spec, flags, mode, cache, pos, table=None,
     h = rms_norm(x, p["ln1"])
     if spec.mixer == ATTN:
         mix, new_cache = _apply_attn(p["attn"], h, cfg, spec, flags, mode,
-                                     cache, pos, table, chunk_valid, plan)
+                                     cache, pos, table, chunk_valid, plan,
+                                     active)
     elif spec.mixer == SSD:
         if mode in ("decode", "paged_decode"):
             mix, new_cache = ssm_mod.decode_step(p["ssd"], h, cache, cfg)

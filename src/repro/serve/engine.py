@@ -121,6 +121,8 @@ class ServeStats:
     pages_peak: int = 0              # peak full-pool pages_in_use over the run
     ring_pages_peak: int = 0         # peak ring-pool pages_in_use (windowed)
     pool_stalls: int = 0             # admissions deferred by PoolExhausted
+    kv_pages_live: int = 0           # table entries the decode kernel read
+    kv_pages_table: int = 0          # table entries it could read (B x N)
     # -- speculative decoding ---------------------------------------------
     spec_steps: int = 0              # draft->verify dispatches
     draft_tokens: int = 0            # draft tokens proposed to the verifier
@@ -139,6 +141,13 @@ class ServeStats:
     prefill_imports: int = 0         # shipped prefills landed into decode slots
     transfer_bytes: int = 0          # bytes crossing the prefill->decode link
     transfer_fallbacks: int = 0      # corrupted transfers recovered by recompute
+
+    @property
+    def kv_live_page_share(self) -> float:
+        """Share of the page tables the decode kernel read: it copies only
+        the pages that hold an active slot's live tokens (a ring table's
+        every slot), summed over ticks, slots and tables."""
+        return self.kv_pages_live / max(1, self.kv_pages_table)
 
     @property
     def accept_rate(self) -> float:
@@ -1314,8 +1323,10 @@ class ServeEngine:
                 return self._spec_dispatch(budgets)
             n_run = min(n, next_pow2(int(budgets.max())))  # pow2: few traces
             with TraceAnnotation("serve.dispatch"):
-                steps = jnp.asarray(np.minimum(budgets, n_run), jnp.int32)
+                steps_h = np.minimum(budgets, n_run)
+                steps = jnp.asarray(steps_h, jnp.int32)
                 if self.backend == "paged":
+                    self._count_kv_pages(steps_h, n_run)
                     (self.cache, self.tokens, self.pos, self.keys,
                      out) = self._paged_decode_many(
                         n_run, self.params, self.cache, self.tokens, self.pos,
@@ -1344,6 +1355,21 @@ class ServeEngine:
                         self._release_finished(i)
                 self.stats.tokens_out += produced
             return produced
+
+    def _count_kv_pages(self, steps: np.ndarray, n_run: int) -> None:
+        """Add an ``n_run``-tick window's page reads to the stats: at tick
+        t a slot with t < steps reads its first ceil((pos + t + 1) / page)
+        table entries (every ring slot), an idle slot none."""
+        tick = np.arange(n_run)[:, None]
+        act = tick < steps[None, :]
+        need = -(-(self._hpos[None, :] + tick + 1) // self.page)
+        for width, ring in ((self.pages_per_seq, False),
+                            (self.ring_slots, True)):
+            if width == 0:
+                continue
+            live = width if ring else np.minimum(need, width)
+            self.stats.kv_pages_live += int((act * live).sum())
+            self.stats.kv_pages_table += n_run * self.bsz * width
 
     def _window_budgets(self, n: int) -> Optional[np.ndarray]:
         """Each slot's token budget for an ``n``-tick window, with the pages

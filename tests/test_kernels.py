@@ -227,8 +227,12 @@ def test_paged_attention_matches_contiguous():
 # (satellite parity sweep — fp32/bf16 x non-divisible lengths vs ref.py)
 # ---------------------------------------------------------------------------
 
-def _fill_pool(k, v, vlen, page, window=None, dtype=None):
-    """Append per-sequence k/v (B, T, Hkv, D) into a fresh page pool."""
+def _fill_pool(k, v, vlen, page, window=None, dtype=None, width=None):
+    """Append per-sequence k/v (B, T, Hkv, D) into a fresh page pool.
+
+    Table entries past a slot's pages hold the next slot's live page ids
+    (not the null page), so a kernel that read past ``valid_len`` would
+    change the answer."""
     from repro.serve.kvcache import PagedKVCache
     b, t, hkv, d = k.shape
     pool = PagedKVCache(num_pages=4 + b * (t // page + 1), page_size=page,
@@ -237,13 +241,19 @@ def _fill_pool(k, v, vlen, page, window=None, dtype=None):
     for i in range(b):
         pool.alloc(i)
         pool.append(i, k[i, :int(vlen[i])], v[i, :int(vlen[i])])
-    table, vl = pool.batch_view(list(range(b)))
-    return pool, table, vl
+    table, vl = pool.batch_view(list(range(b)), width)
+    table = np.array(table)
+    for i in range(b):
+        other = pool.tables[(i + 1) % b] or [0]
+        for j in range(len(pool.tables[i]), table.shape[1]):
+            table[i, j] = other[j % len(other)]
+    return pool, jnp.asarray(table), vl
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
                                        (jnp.bfloat16, 3e-2)])
-@pytest.mark.parametrize("vlens", [[7, 100, 256], [1, 53, 255]])
+@pytest.mark.parametrize("vlens", [[7, 100, 256], [1, 53, 255],
+                                   [31, 32, 33], [255, 1, 256]])
 def test_paged_attention_softcap(dtype, tol, vlens):
     """satellite: the paged kernel's softcap path (gemma2) vs the dense
     oracle, across dtypes and non-divisible lengths."""
@@ -263,7 +273,9 @@ def test_paged_attention_softcap(dtype, tol, vlens):
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
                                        (jnp.bfloat16, 3e-2)])
 @pytest.mark.parametrize("window,vlens", [(32, [7, 100, 250]),
-                                          (24, [1, 33, 256])])
+                                          (24, [1, 33, 256]),
+                                          (24, [15, 16, 17]),
+                                          (250, [255, 1, 256])])
 def test_paged_attention_ring_window(dtype, tol, window, vlens):
     """Ring tables: the pool holds only ceil(window/page)+1 pages per
     sequence, yet attention over the live window is exact."""
@@ -291,7 +303,8 @@ def test_paged_attention_ring_window(dtype, tol, window, vlens):
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
                                        (jnp.bfloat16, 3e-2)])
-@pytest.mark.parametrize("vlens", [[7, 100, 250], [1, 64, 255]])
+@pytest.mark.parametrize("vlens", [[7, 100, 250], [1, 64, 255],
+                                   [31, 32, 33], [255, 1, 256]])
 def test_paged_attention_int8_pages_match_dense_int8(dtype, tol, vlens):
     """satellite: int8 pages + per-token scale lanes dequantized in-kernel
     == dense int8-KV attention (quantize once, dequantize outside)."""
@@ -320,6 +333,51 @@ def test_paged_attention_int8_pages_match_dense_int8(dtype, tol, vlens):
     _close(got, want, tol)
     _close(ref.paged_attention(q, pool.k_pages, pool.v_pages, table, vl,
                                k_scale=ks, v_scale=vs), want, tol)
+
+
+@pytest.mark.parametrize("hkv,g,window", [(1, 1, None), (1, 3, 300),
+                                          (2, 1, 300), (2, 3, None),
+                                          (8, 1, None), (8, 3, 300)])
+@pytest.mark.parametrize("kv,tol", [("float32", 1e-4), ("bfloat16", 3e-2),
+                                    ("int8", 1e-4)])
+def test_paged_attention_blocks(hkv, g, window, kv, tol):
+    """satellite: multi-page blocks, all KV heads a step, with softcap —
+    valid lengths of 1, page-1, page, a block +-1 token and the whole
+    table, every table entry past a slot's pages another slot's live page,
+    against dense attention over the logical sequence."""
+    from repro.kernels.paged_attention import pages_per_block
+    from repro.models.attention import AttnParams, naive_attention
+    from repro.models.transformer import _kv_quant
+    page, d = 8, 128
+    blk = page * pages_per_block(page, 1 << 20, hkv, d, kv)
+    t = blk + 2 * page
+    vlen = jnp.asarray([1, page - 1, page, blk - 1, blk, blk + 1, t],
+                       jnp.int32)
+    b = vlen.shape[0]
+    qdt = jnp.bfloat16 if kv == "bfloat16" else jnp.float32
+    q = _arr((b, hkv * g, d), qdt)
+    k, v = _arr((b, t, hkv, d), qdt), _arr((b, t, hkv, d), qdt)
+    width = None if window else t // page
+    scales = {}
+    if kv == "int8":
+        (k, ks), (v, vs) = _kv_quant(k), _kv_quant(v)
+        sp, _, _ = _fill_pool(ks[..., None, None], vs[..., None, None],
+                              vlen, page, window, width=width)
+        scales = dict(k_scale=sp.k_pages[..., 0, 0],
+                      v_scale=sp.v_pages[..., 0, 0])
+        kd = k.astype(jnp.float32) * ks[..., None, None]
+        vd = v.astype(jnp.float32) * vs[..., None, None]
+    else:
+        kd, vd = k, v
+    pool, table, vl = _fill_pool(k, v, vlen, page, window, width=width)
+    got = ops.paged_attention(q, pool.k_pages, pool.v_pages, table, vl,
+                              softcap=30.0, window=window, **scales)
+    kpos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
+    kpos = jnp.where(kpos < vl[:, None], kpos, -10**9)
+    want = naive_attention(q[:, None], kd.astype(qdt), vd.astype(qdt),
+                           AttnParams(window=window, softcap=30.0),
+                           q_offset=vl - 1, k_positions=kpos)[:, 0]
+    _close(got, want, tol)
 
 
 def test_paged_pool_alloc_release():

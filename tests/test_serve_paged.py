@@ -432,6 +432,47 @@ def test_long_prompt_prefills_in_chunks_between_decode_ticks():
     assert stats.decode_dispatches > 2
 
 
+def test_kv_page_counters_follow_slot_lengths():
+    """``kv_pages_live`` counts the table entries the decode kernel reads —
+    ceil((pos + 1) / page) a tick for each slot that advances, none for a
+    slot that waits (here a long prompt still prefilling) — as the device
+    positions each window starts from say; ``kv_pages_table`` counts B x
+    table width a tick.  The dense backend counts neither."""
+    cfg = smoke_config(ARCHS["gemma-2b"])
+    bundle = build(cfg, FLAGS)
+    params = bundle.init(jax.random.PRNGKey(5))
+
+    def drain(backend):
+        eng = ServeEngine(bundle, params, batch_size=2, max_len=64,
+                          window=4, prefill_chunk=8, cache_backend=backend)
+        seen = []
+        if backend == "paged":
+            inner = eng._paged_decode_many
+
+            def spy(n, params, cache, tokens, pos, steps, keys, table):
+                seen.append((n, np.asarray(pos), np.asarray(steps)))
+                return inner(n, params, cache, tokens, pos, steps, keys,
+                             table)
+            eng._paged_decode_many = spy
+        eng.add_request(Request(rid=0, prompt=np.arange(4, dtype=np.int32),
+                                max_new_tokens=20))
+        eng.add_request(Request(rid=1, max_new_tokens=6,
+                                prompt=np.arange(30, dtype=np.int32) + 50))
+        return eng, eng.run_to_completion(), seen
+
+    eng, stats, seen = drain("paged")
+    width = eng.pages_per_seq
+    live = sum(int(np.minimum(-(-(pos + t + 1) // eng.page), width)[
+        t < steps].sum()) for n, pos, steps in seen for t in range(n))
+    assert any((steps == 0).any() for _, _, steps in seen)
+    assert stats.kv_pages_live == live > 0
+    assert stats.kv_pages_table == sum(n for n, _, _ in seen) * 2 * width
+    assert 0 < stats.kv_live_page_share < 1
+    _, dense, _ = drain("dense")
+    assert dense.kv_pages_live == dense.kv_pages_table == 0
+    assert dense.kv_live_page_share == 0
+
+
 # ---------------------------------------------------------------------------
 # prefix caching (tentpole; satellite 3's fork test is above)
 # ---------------------------------------------------------------------------

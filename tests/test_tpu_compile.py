@@ -27,6 +27,8 @@ from repro.kernels.paged_attention import paged_attention
 from repro.tune import plan_for
 
 BATCH, MAX_LEN, HQ, HKV, D = 8, 2048, 24, 8, 128
+# internlm2-20b's shard of heads under TP=4 (48 q / 8 kv heads over 4 chips)
+TP4_HQ, TP4_HKV = 12, 2
 
 
 @pytest.fixture(scope="module")
@@ -68,13 +70,15 @@ def _paged_shapes(kv_dtype, window=None):
     return plan, page, slots, pool
 
 
-@pytest.mark.parametrize("variant", ["bf16", "window_softcap", "int8"])
+@pytest.mark.parametrize("variant", ["bf16", "window_softcap", "int8",
+                                     "tp4_bf16"])
 def test_paged_attention_compiles(one_chip, variant):
     kv = jnp.int8 if variant == "int8" else jnp.bfloat16
     window = 512 if variant == "window_softcap" else None
+    hq, hkv = (TP4_HQ, TP4_HKV) if variant == "tp4_bf16" else (HQ, HKV)
     plan, page, slots, pool = _paged_shapes(kv, window)
-    shapes = [((BATCH, HQ, D), jnp.bfloat16),
-              ((pool, page, HKV, D), kv), ((pool, page, HKV, D), kv),
+    shapes = [((BATCH, hq, D), jnp.bfloat16),
+              ((pool, page, hkv, D), kv), ((pool, page, hkv, D), kv),
               ((BATCH, slots), jnp.int32), ((BATCH,), jnp.int32)]
     if variant == "int8":
         shapes += [((pool, page), jnp.float32)] * 2
@@ -109,6 +113,29 @@ def test_paged_attention_keeps_its_name(one_chip, wrapped):
                        text)
     assert calls
     assert all(re.fullmatch(r"paged_attention(\.\d+)*", c) for c in calls)
+
+
+def test_paged_attention_reads_the_pool_in_place(one_chip):
+    """At phi4-mini's decode shapes the compiled call holds no copy,
+    transpose or fusion of a pool-sized array: the kernel reads each page
+    from the pool as it lies (the per-head relayout, bf16[16392,8,128] a
+    call for K and for V, is gone)."""
+    plan, page, slots, pool = _paged_shapes(jnp.bfloat16)
+
+    def decode_window(q, kp, vp, t, vl):
+        return paged_attention(q, kp, vp, t, vl, plan=plan, interpret=False)
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((BATCH, HQ, D), jnp.bfloat16), ((pool, page, HKV, D), jnp.bfloat16),
+        ((pool, page, HKV, D), jnp.bfloat16), ((BATCH, slots), jnp.int32),
+        ((BATCH,), jnp.int32))]
+    text = jax.jit(decode_window).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    shapes = "|".join(re.escape(",".join(map(str, s))) for s in (
+        (pool, page, HKV, D), (pool * HKV, page, D)))
+    moved = re.findall(rf"= \w+\[({shapes})\]\S* (copy|copy-start|"
+                       rf"transpose|fusion)\(", text)
+    assert not moved, moved
 
 
 def test_decode_attention_compiles(one_chip):

@@ -1,2 +1,3 @@
 """Chip benchmark of the paged serving path: configurations, traffic mixes,
-metric readers and the plain reference, driven by ``BENCHMARK.json``."""
+model families (the program's model, weights, plain reference and
+operation count of each), metric readers, driven by ``BENCHMARK.json``."""

@@ -10,6 +10,7 @@ that hands it back.
 """
 from __future__ import annotations
 
+import functools
 import gc
 import importlib
 import importlib.util
@@ -22,9 +23,11 @@ import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from chipbench import flops, model, reference, trace
+from chipbench import model, trace
 from chipbench.gen import common
 
 
@@ -134,24 +137,24 @@ def prefill_buckets(lengths, chunk: int) -> List[int]:
 
 
 class System:
-    """The served model, built once: weights drawn from a seed, the
-    engines and their front end."""
+    """The served model of ``conf``'s ``family``, built once: weights
+    drawn from a seed, the engines and their front end."""
 
-    def __init__(self, root: str, conf: dict, devices, seed: int):
-        import jax
-
+    def __init__(self, conf: dict, family, devices, seed: int):
         from repro.dist import ServeMesh
         from repro.launch.serve import build_pool
         from repro.serve import ClusterFrontEnd
 
-        self.conf = conf
+        self.conf, self.family = conf, family
         self.devices = list(devices)
-        self.bundle = model.bundle_for(conf)
+        self.bundle = family.build(conf)
+        self.draw = functools.partial(family.draw, conf)
         tp = conf["tp"]
         self.shardings = (ServeMesh.tp(tp, devices=self.devices)
                           .param_shardings(self.bundle) if tp > 1 else None)
         t = time.perf_counter()
-        self.params = model.make_params(self.bundle, seed, self.shardings)
+        self.params = model.make_params(self.bundle, self.draw, seed,
+                                        self.shardings)
         jax.block_until_ready(self.params)
         self.weights_s = time.perf_counter() - t
         eng = conf["engine"]
@@ -178,7 +181,8 @@ class System:
             e.params = None
         self.params = None
         gc.collect()
-        self.params = model.make_params(self.bundle, seed, self.shardings)
+        self.params = model.make_params(self.bundle, self.draw, seed,
+                                        self.shardings)
         for e in self.engines:
             e.params = self.params
         self.front.reset()
@@ -239,20 +243,17 @@ def warm_up(system: System, mix: dict, vocab: int) -> int:
 class Tracer:
     """The profiler over a sub-window of the run (``trace 1`` only)."""
 
-    def __init__(self, start: float, seconds: float):
+    def __init__(self, start: float, seconds: float, counts: dict):
         self.start, self.seconds = start, seconds
         self.state = "waiting"
         self.dir = None
         self.rounds = 0
         self.t0 = self.t1 = 0.0
-        self.flops = 0
-        # decode attention's operations and the K/V bytes it must read
-        self.attn_flops = self.attn_bytes = 0
+        # the family's counts of the tokens returned in the traced window
+        self.counts = dict(counts)
         self._span = None
 
     def before_round(self, now: float) -> None:
-        import jax
-
         if self.state == "waiting" and now >= self.start:
             self.dir = tempfile.mkdtemp(prefix="chipbench-trace-")
             opts = jax.profiler.ProfileOptions()
@@ -263,20 +264,17 @@ class Tracer:
             self.t0 = time.perf_counter()
             self.state = "on"
 
-    def after_round(self, now: float, ops: int, attn=(0, 0)) -> None:
+    def after_round(self, now: float, counts: dict) -> None:
         if self.state != "on":
             return
         self.rounds += 1
-        self.flops += ops
-        self.attn_flops += attn[0]
-        self.attn_bytes += attn[1]
+        for name, n in counts.items():
+            self.counts[name] = self.counts.get(name, 0) + n
         if now >= self.t0 + self.seconds:
             self.close()
 
     def close(self) -> None:
         """Stop the profiler, if it is on."""
-        import jax
-
         if self.state == "on":
             self.t1 = time.perf_counter()
             self._span.__exit__(None, None, None)
@@ -286,8 +284,6 @@ class Tracer:
 
 def _span(name: str, on: bool):
     import contextlib
-
-    import jax
 
     return jax.profiler.TraceAnnotation(name) if on else contextlib.nullcontext()
 
@@ -329,19 +325,17 @@ class Window:
             self.system.front.step()
         t = time.perf_counter()
         self.rounds += 1
-        done, ops, attn = [], 0, [0, 0]
+        done, counts = [], {}
         with _span("chipbench.harvest", on):
             for rec in self.live:
                 n = len(rec.req.out_tokens)
                 if n > rec.got:
                     if on:
-                        s = len(rec.req.prompt)
-                        ops += flops.served(self.conf, s, rec.got,
-                                            n - rec.got)
-                        f, b = flops.decode_attention(self.conf, s, rec.got,
-                                                      n - rec.got)
-                        attn[0] += f
-                        attn[1] += b
+                        c = self.system.family.counts(
+                            self.conf, len(rec.req.prompt), rec.got,
+                            n - rec.got)
+                        for name, k in c.items():
+                            counts[name] = counts.get(name, 0) + k
                     if rec.first is None:
                         rec.first = t
                     rec.last = t
@@ -352,7 +346,7 @@ class Window:
             if done:
                 self.live = [r for r in self.live if r.finish is None]
         if tr is not None:
-            tr.after_round(t, ops, attn)
+            tr.after_round(t, counts)
         return done
 
     # -- open loop ------------------------------------------------------
@@ -458,20 +452,31 @@ def summarize(gaps: np.ndarray) -> dict:
 BLOCK = 256
 
 
-def compare(system: System, recs: List[Rec], precs=("f32",)) -> dict:
-    """Each sampled request through the reference, prompt and the tokens
-    served to its client together, padded to ``max_len``.  For ``"f32"``
-    the gap of each served token below the reference's best logit; for a
-    control precision, at the same positions, the gap of the token that
-    it puts first.  Rows go through the LM head in blocks of ``BLOCK``
-    (one shape, compiled once).  Returns ``summarize`` of each
-    precision's gaps; ``precs`` holds ``"f32"``."""
-    import jax.numpy as jnp
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def gaps(logits, prec, params, h_ref, h_ctl, served):
+    """Per row: how far below the reference's best logit lies the served
+    token, and the token the ``prec`` logits put first (the control's
+    pick; with ``prec="f32"`` it is the reference's own argmax).
+    ``logits(prec, params, h)`` is the family's LM head."""
+    ref = logits("f32", params, h_ref)
+    best = ref.max(-1)
+    at = jnp.take_along_axis(ref, served[:, None], axis=-1)[:, 0]
+    pick = logits(prec, params, h_ctl).argmax(-1)
+    at_pick = jnp.take_along_axis(ref, pick[:, None], axis=-1)[:, 0]
+    return best - at, best - at_pick
 
-    conf = system.conf
+
+def compare(system: System, recs: List[Rec], precs=("f32",)) -> dict:
+    """Each sampled request through the family's reference, prompt and
+    the tokens served to its client together, padded to ``max_len``.  For
+    ``"f32"`` the gap of each served token below the reference's best
+    logit; for a control precision, at the same positions, the gap of the
+    token that it puts first.  Rows go through the LM head in blocks of
+    ``BLOCK`` (one shape, compiled once).  Returns ``summarize`` of each
+    precision's gaps; ``precs`` holds ``"f32"``."""
+    conf, family = system.conf, system.family
     max_len = conf["engine"]["max_len"]
-    items = reference.conf_items(conf)
-    gaps = {p: [] for p in precs}
+    found = {p: [] for p in precs}
     compared = 0
     for rec in recs:
         out = np.asarray(rec.req.out_tokens[:rec.got], np.int32)
@@ -480,7 +485,7 @@ def compare(system: System, recs: List[Rec], precs=("f32",)) -> dict:
         ctx = np.concatenate([prompt, out[:-1]])
         seq[:len(ctx)] = ctx
         rows = len(prompt) - 1 + np.arange(len(out))
-        h = {p: reference.hidden(items, p, system.params, jnp.asarray(seq))
+        h = {p: family.hidden(conf, p, system.params, jnp.asarray(seq))
              for p in precs}
         for b in range(0, len(out), BLOCK):
             n = min(BLOCK, len(out) - b)
@@ -490,14 +495,13 @@ def compare(system: System, recs: List[Rec], precs=("f32",)) -> dict:
             o[:n] = out[b:b + n]
             r = jnp.asarray(r)
             for p in precs:
-                g_served, g_pick = reference.gaps(p, system.params,
-                                                  h["f32"][r], h[p][r],
-                                                  jnp.asarray(o))
+                g_served, g_pick = gaps(family.logits, p, system.params,
+                                        h["f32"][r], h[p][r], jnp.asarray(o))
                 g = g_served if p == "f32" else g_pick
-                gaps[p].append(np.asarray(g)[:n])
+                found[p].append(np.asarray(g)[:n])
         compared += len(out)
     numbers = {p: summarize(np.concatenate(g)) if g else None
-               for p, g in gaps.items()}
+               for p, g in found.items()}
     return dict(numbers=numbers, compared=compared)
 
 
@@ -515,8 +519,6 @@ def valid_outputs(recs: List[Rec], vocab: int) -> bool:
 # a whole run
 # ----------------------------------------------------------------------
 def devices_for(chips: int, require_tpu: bool):
-    import jax
-
     devs = jax.devices()
     if require_tpu and devs[0].platform != "tpu":
         raise NoChip(f"no TPU: JAX's first device is {devs[0].platform}")
@@ -544,14 +546,14 @@ class Cell:
         self.bench = load_bench(root)
         self.cell = next(w for w in self.bench["workloads"]
                          if w["name"] == workload)
-        self.conf = model.load_config(root, self.cell["config"])
+        self.conf, self.family = model.load_config(root, self.cell["config"])
         self.mix = load_mix(root, self.cell["traffic"])
         common.check_lengths(self.mix, self.conf["engine"]["max_len"])
         self.devices = devices_for(self.cell["chips"], require_tpu)
         self.kind = self.devices[0].device_kind
         self.peaks = peaks or peaks_for(self.kind)
         self.compiles = Compiles()
-        self.system = System(root, self.conf, self.devices, seed)
+        self.system = System(self.conf, self.family, self.devices, seed)
         t = time.perf_counter()
         rounds = warm_up(self.system, self.mix, self.conf["vocab_size"])
         self.log(f"set-up: weights {self.system.weights_s:.3f}s, engines "
@@ -571,7 +573,8 @@ class Cell:
         if trace_on:
             # opens only once ``start`` is set, after a closed loop's priming
             tracer = Tracer(float("inf"),
-                            min(float(mix["trace_seconds"]), seconds))
+                            min(float(mix["trace_seconds"]), seconds),
+                            self.family.counts(self.conf, 0, 0, 0))
         win = Window(system, mix, gen, self.conf, tracer)
         if gen.kind == "closed_loop":
             win.prime()
@@ -678,8 +681,6 @@ def run(root: str, workload: str, seed: int, seconds: float, trace_on: bool,
     ``control`` (``"int8"`` or ``"fp8"``) judges, in the served tokens'
     place, the tokens that the reference in that precision puts first at
     the same positions: the check's control, never a benchmark run."""
-    import jax
-
     cell = Cell(root, workload, seed, require_tpu=require_tpu, peaks=peaks,
                 log=log)
     served = cell.serve(seed, seconds, trace_on, t_start=t_start)
@@ -741,9 +742,8 @@ def per_layer(cell: Cell, tracer: Tracer, on_trace=None):
         raw = trace.extract(trace.find_xplane(tracer.dir))
     finally:
         shutil.rmtree(tracer.dir, ignore_errors=True)
-    host = dict(rounds=tracer.rounds, host_s=tracer.t1 - tracer.t0,
-                flops=tracer.flops, attn_flops=tracer.attn_flops,
-                attn_bytes=tracer.attn_bytes, chips=len(cell.devices),
+    host = dict(tracer.counts, rounds=tracer.rounds,
+                host_s=tracer.t1 - tracer.t0, chips=len(cell.devices),
                 peaks=cell.peaks, devices=[d.id for d in cell.devices])
     if on_trace is not None:
         on_trace(raw, host)

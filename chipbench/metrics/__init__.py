@@ -6,7 +6,9 @@ holds nothing to read.
 
 ``run`` holds ``trace`` (``trace.reduce``'s record of the traced window),
 ``rounds`` (``ClusterFrontEnd.step()`` calls in that window), ``host_s``
-(the window on the host clock), ``flops`` (``flops.served`` summed over the
-tokens returned in the window), ``chips`` and ``peaks`` (``peaks.json``'s
-entry for the device).
+(the window on the host clock), ``chips``, ``peaks`` (``peaks.json``'s
+entry for the device) and each of the configuration family's ``counts``
+(``chipbench/families/<family>.py``), summed over the tokens returned in
+the window: ``flops`` (forward operations), ``attn_flops`` and
+``attn_bytes`` (decode attention's operations and K/V bytes).
 """

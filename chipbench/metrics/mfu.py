@@ -1,5 +1,6 @@
 """The whole model step's share of the chips' bf16 peak, in %: forward
-operations of the tokens returned in the traced window (``flops.served``)
+operations of the tokens returned in the traced window (``flops`` of the
+configuration family's ``counts``, ``chipbench/families/<family>.py``)
 over window x chips x peak."""
 
 

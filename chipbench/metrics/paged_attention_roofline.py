@@ -1,8 +1,9 @@
 """Decode attention's share of its roofline, in %: the least time the
 chip needs for the work (the larger of its operations over the bf16 peak
-and of the K/V bytes it must read over HBM bandwidth, both from
-``flops.decode_attention`` for the tokens decoded in the traced window,
-split evenly over the chips) over the device time of the
+and of the K/V bytes it must read over HBM bandwidth: ``attn_flops`` and
+``attn_bytes`` of the configuration family's ``counts`` in
+``chipbench/families/<family>.py`` for the tokens decoded in the traced
+window, split evenly over the chips) over the device time of the
 ``paged_attention`` kernel's ops on the first chip."""
 import re
 

@@ -1,69 +1,73 @@
-"""A configuration file, the model it describes, and the weights the
+"""A configuration file, the model family it names, and the weights the
 benchmark draws for it.
 
-The configuration file is the yardstick: the program's model is built from
-the file's sizes, and the reference (``reference.py``) follows the file.
+The configuration file is the yardstick: ``chipbench/configs/<name>.json``
+holds a published configuration's keys as they are run, and its
+``"family"`` names the module ``chipbench/families/<family>.py`` that
+holds everything that depends on the architecture.  The module is loaded
+by its path under the checkout's root, so a family comes in as a file of
+its own.  It provides:
+
+- ``build(conf)``: the program's model for the file (a
+  ``repro.models.ModelBundle``), refusing a file the program cannot run
+  as stated;
+- ``draw(conf, names, shape, key)``: one weight, float32, from its
+  parameter path (the list of key names) and shape;
+- ``hidden(conf, prec, params, tokens)``: the plain reference's
+  final-normed hidden states (S, d) of a row of ``tokens`` (S,), float32,
+  causal, importing nothing of the program; ``prec`` is ``"f32"``, or a
+  control precision (``"int8"``, ``"fp8"``) for every matrix product;
+- ``logits(prec, params, h)``: the reference's logits of such rows;
+- ``counts(conf, prompt, first, count)``: named counts (operations,
+  bytes) behind output tokens ``first .. first + count - 1`` of a request
+  with a ``prompt``-token prompt, all 0 for ``count`` 0.  The harness sums
+  each over the tokens returned in a traced window and hands the sums to
+  the metric readers under the same names (``chipbench/metrics``), so a
+  family brings the counts its own readers need.
+
 The weights are the benchmark's own, drawn on the device from the seed in
 one jitted call, in the parameter layout the program's model takes (the
 program never draws them).
 """
 from __future__ import annotations
 
-import dataclasses
+import importlib.util
 import json
-import math
 import os
 
 import numpy as np
 
-# file key -> the program's ModelConfig field
-_FIELDS = {
-    "num_hidden_layers": "num_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "tie_word_embeddings": "tie_embeddings",
-}
-# what the program's dense decoder fixes and the file has to state alike
-_FIXED = {"hidden_act": "silu", "partial_rotary_factor": 1.0,
-          "rope_scaling": None, "rms_norm_eps": 1e-6}
-NORMS = ("ln1", "ln2", "final_norm")
+
+def load_family(root: str, family: str):
+    """The module ``chipbench/families/<family>.py`` under ``root``."""
+    path = os.path.join(root, "chipbench", "families", f"{family}.py")
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_family_{family}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
-def load_config(root: str, name: str) -> dict:
-    """The configuration as it is run: the file's keys, which follow the
-    published configuration, with each of its ``departures`` (what the
-    program runs in place of a published key) put in."""
-    with open(os.path.join(root, "chipbench", "configs", f"{name}.json")) as f:
+def load_config(root: str, name: str):
+    """The configuration as it is run and its family's module.  The
+    configuration is the file's keys, which follow the published
+    configuration, with each of its ``departures`` (what the program runs
+    in place of a published key) put in.  A file that names no family, or
+    one with no module, is refused."""
+    path = os.path.join(root, "chipbench", "configs", f"{name}.json")
+    with open(path) as f:
         conf = json.load(f)
+    family = conf.get("family")
+    if not family:
+        raise ValueError(f"{path} names no model family (its \"family\" "
+                         f"key names chipbench/families/<family>.py)")
+    if not os.path.exists(os.path.join(root, "chipbench", "families",
+                                       f"{family}.py")):
+        raise ValueError(f"{path} names the family {family!r}, which has no "
+                         f"module chipbench/families/{family}.py")
     for key, dep in conf.get("departures", {}).items():
         conf[key] = dep["run"]
-    return conf
-
-
-def bundle_for(conf: dict):
-    """The program's model for ``conf``: its registry entry for
-    ``conf["arch"]`` with the file's sizes, and the serving runtime flags
-    of ``repro.launch.serve.build_bundle``."""
-    from repro.launch.serve import build_bundle
-    from repro.models import build
-
-    for key, want in _FIXED.items():
-        if conf[key] != want:
-            raise ValueError(f"{conf['arch']}: {key}={conf[key]!r}, but the "
-                             f"program's dense decoder runs {want!r}")
-    base = build_bundle(conf["arch"])
-    if base.cfg.activation != "swiglu" or base.cfg.family != "dense":
-        raise ValueError(f"{conf['arch']} is not a dense SwiGLU decoder")
-    sizes = {field: conf[key] for key, field in _FIELDS.items()}
-    cfg = dataclasses.replace(base.cfg, **sizes,
-                              param_dtype=conf["dtype"],
-                              compute_dtype=conf["dtype"])
-    return build(cfg, base.flags)
+    return conf, load_family(root, family)
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -71,34 +75,24 @@ def seed_words(seed: int) -> np.ndarray:
     return np.random.SeedSequence(int(seed)).generate_state(2, np.uint32)
 
 
-def make_params(bundle, seed: int, shardings=None):
+def make_params(bundle, draw, seed: int, shardings=None):
     """Weights for ``bundle`` from ``seed``, on the device, in one jitted
     call (the seed is an argument, so every seed runs the same program).
-
-    Matrices are truncated normal with std ``1/sqrt(fan_in)`` (the
-    embedding ``1/sqrt(hidden_size)``); norm gains are ``1 + 0.1 N(0, 1)``,
-    held as the offset from 1 that the program's RMSNorm adds to 1."""
+    Leaf ``i`` of the parameter tree is ``draw(names, shape, key)`` with
+    the ``i``-th key folded from the seed, cast to the leaf's dtype."""
     import jax
     import jax.numpy as jnp
 
     abstract, _ = bundle.abstract_params()
     paths, treedef = jax.tree_util.tree_flatten_with_path(abstract)
-    d = bundle.cfg.d_model
 
     def init(words):
         key = jax.random.fold_in(jax.random.fold_in(
             jax.random.PRNGKey(0), words[0]), words[1])
         out = []
         for i, (path, leaf) in enumerate(paths):
-            k = jax.random.fold_in(key, i)
             names = [str(getattr(p, "key", "")) for p in path]
-            if names[-1] in NORMS:
-                v = 0.1 * jax.random.normal(k, leaf.shape, jnp.float32)
-            else:
-                std = (d ** -0.5 if names[0] == "embed"
-                       else 1.0 / math.sqrt(leaf.shape[-2]))
-                v = std * jax.random.truncated_normal(k, -2.0, 2.0,
-                                                      leaf.shape, jnp.float32)
+            v = draw(names, leaf.shape, jax.random.fold_in(key, i))
             out.append(v.astype(leaf.dtype))
         return jax.tree_util.tree_unflatten(treedef, out)
 

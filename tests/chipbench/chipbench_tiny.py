@@ -1,6 +1,6 @@
 """A checkout with a tiny configuration for the benchmark's CPU tests: the
 benchmark's own files, the program beside them, and a BENCHMARK.json whose
-cells serve a two-layer model of the phi4-mini-3.8b family."""
+cells serve a two-layer model of the phi4-mini-3.8b architecture."""
 import json
 import os
 import shutil
@@ -9,7 +9,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 TINY = {
-    "arch": "phi4-mini-3.8b", "source": "tiny test model", "tp": 1,
+    "arch": "phi4-mini-3.8b", "family": "dense_gqa",
+    "source": "tiny test model", "tp": 1,
     "dtype": "bfloat16", "hidden_act": "silu", "hidden_size": 64,
     "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
@@ -34,8 +35,10 @@ MIXES = {
 CPU_PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
 
 
-def make_root(tmp_path, conf=None) -> str:
-    """A checkout under ``tmp_path``; ``conf`` replaces keys of TINY."""
+def make_root(tmp_path, conf=None, families=None) -> str:
+    """A checkout under ``tmp_path``; ``conf`` replaces keys of TINY, and
+    each of ``families`` (name -> source) is written to
+    ``chipbench/families/<name>.py``."""
     root = os.path.join(str(tmp_path), "checkout")
     shutil.copytree(os.path.join(REPO, "chipbench"),
                     os.path.join(root, "chipbench"),
@@ -44,6 +47,10 @@ def make_root(tmp_path, conf=None) -> str:
     with open(os.path.join(root, "chipbench", "configs", "tiny.json"),
               "w") as f:
         json.dump(dict(TINY, **(conf or {})), f)
+    for name, source in (families or {}).items():
+        with open(os.path.join(root, "chipbench", "families", f"{name}.py"),
+                  "w") as f:
+            f.write(source)
     for name, mix in MIXES.items():
         with open(os.path.join(root, "chipbench", "mixes", f"{name}.json"),
                   "w") as f:

@@ -2,7 +2,7 @@
 chip skipped: sound runs come out correct, and each fault a serving cell
 can have, planted under the timed path, comes out not correct.  The
 control (the reference put in the program's place, in fp8) comes out not
-correct by the same limits."""
+correct by the same limits.  A model family comes in as a file alone."""
 import json
 import os
 import subprocess
@@ -143,6 +143,29 @@ def test_the_control_fails_the_limit(root):
         T.TINY["checks"]["min_compared_tokens"]
     mean = res["checks"]["mean_logit_gap"]
     assert mean["value"] > mean["limit"]
+
+
+with open(os.path.join(T.REPO, "chipbench", "families", "dense_gqa.py")) as f:
+    DENSE_SOURCE = f.read()
+RESIDUAL = 'x = x + _mm(o, a["wo"], prec)'
+
+
+@pytest.mark.parametrize("source,correct", [
+    (DENSE_SOURCE, True),
+    (DENSE_SOURCE.replace(RESIDUAL, 'x = _mm(o, a["wo"], prec)'), False),
+], ids=["sound", "reference-drops-a-residual"])
+def test_a_family_comes_in_as_a_file_alone(tmp_path, source, correct):
+    """A checkout that adds ``families/tiny_gqa.py`` and a configuration
+    naming it, and edits no other file, serves a cell through that
+    family; the cell is judged by that file's reference, so a reference
+    that leaves out attention's residual add fails the same run."""
+    assert RESIDUAL in DENSE_SOURCE
+    root = T.make_root(tmp_path, dict(family="tiny_gqa"),
+                       families={"tiny_gqa": source})
+    res = _run(root, "tiny-closed")
+    assert res["correct"] is correct, res["checks"]
+    gap = res["checks"]["max_logit_gap"]
+    assert (gap["value"] <= gap["limit"]) is correct
 
 
 def test_a_tp2_cell_runs_correct_on_two_virtual_devices(tmp_path):

@@ -1,6 +1,7 @@
 """The benchmark's yardstick on the CPU: trace reduction, readers, the
-FLOP count, the peak table, the generators, and the refusals of a run
-that has no chip or no program.  Nothing here touches a TPU."""
+dense family's FLOP count, weights and reference, the peak table, the
+generators, and the refusals of a run that has no chip, no program or no
+family.  Nothing here touches a TPU."""
 import json
 import os
 import subprocess
@@ -10,16 +11,17 @@ import types
 import numpy as np
 import pytest
 
-from chipbench_tiny import MIXES, REPO
+from chipbench_tiny import MIXES, REPO, TINY
 
 sys.path.insert(0, REPO)
 
-from chipbench import flops, harness, peaks, trace  # noqa: E402
+from chipbench import harness, model, peaks, trace  # noqa: E402
 from chipbench.gen import closed_loop, common, open_loop  # noqa: E402
 
 FIXTURES = os.path.join(REPO, "chipbench", "fixtures")
 BENCH = harness.load_bench(REPO)
 CELLS = [w["name"] for w in BENCH["workloads"]]
+DENSE = model.load_family(REPO, "dense_gqa")
 
 
 def _raw(device_ops, window=(0.0, 100.0), modules=()):
@@ -116,19 +118,108 @@ SMALL = {"hidden_size": 8, "intermediate_size": 16, "num_hidden_layers": 2,
 def test_flops_against_a_hand_count():
     # one layer, one token: q 8x8, k and v 8x4 each, o 8x8, three 8x16
     # mlp matrices = 64 + 64 + 64 + 384 = 576 multiply-adds
-    assert flops.layer_matmul(SMALL) == 2 * 576
+    assert DENSE.layer_matmul(SMALL) == 2 * 576
     # positions 3, 4: 4 + 5 keys, 4 heads of 2 dims, qk and pv
-    assert flops.attention(SMALL, 3, 2) == 4 * 4 * 2 * 9
-    assert flops.head(SMALL) == 2 * 8 * 10
+    assert DENSE.attention(SMALL, 3, 2) == 4 * 4 * 2 * 9
+    assert DENSE.head(SMALL) == 2 * 8 * 10
     # a 3-token prompt and its first two output tokens: the prompt fed at
     # positions 0..2, output token 0 fed at position 3, two LM heads
     want = (2 * (3 * 1152 + 32 * 6)
             + 2 * (1152 + 32 * 4)
             + 2 * 160)
-    assert flops.served(SMALL, 3, 0, 2) == want
+    assert DENSE.served(SMALL, 3, 0, 2) == want
     # served in two rounds, the sum is the same
-    assert flops.served(SMALL, 3, 0, 1) + flops.served(SMALL, 3, 1, 1) == want
-    assert flops.served(SMALL, 3, 5, 0) == 0
+    assert DENSE.served(SMALL, 3, 0, 1) + DENSE.served(SMALL, 3, 1, 1) == want
+    assert DENSE.served(SMALL, 3, 5, 0) == 0
+
+
+def test_decode_attention_and_counts_against_a_hand_count():
+    # output tokens 0..2 of a 3-token prompt: token 0 comes from the
+    # prompt; tokens 1 and 2 are fed at positions 3 and 4 and read 4 + 5
+    # cached rows of 2 KV heads x 2 dims, K and V, 2 bytes each, a layer
+    ops, nbytes = DENSE.decode_attention(SMALL, 3, 0, 3)
+    assert ops == 2 * DENSE.attention(SMALL, 3, 2)
+    assert nbytes == 2 * 9 * 4 * 2 * 2
+    assert DENSE.decode_attention(SMALL, 3, 0, 1) == (0, 0)
+    # what the readers get: these under their names, and 0 for no token
+    assert DENSE.counts(SMALL, 3, 0, 3) == {
+        "flops": DENSE.served(SMALL, 3, 0, 3), "attn_flops": ops,
+        "attn_bytes": nbytes}
+    assert set(DENSE.counts(SMALL, 0, 0, 0).values()) == {0}
+
+
+# The dense family's draw and reference as they stood when they were moved
+# behind the family module: the tiny configuration's weights from SEED
+# (each leaf's sum and sum of magnitudes), and the reference's float32
+# hidden states and logits of ROW (sum, sum of magnitudes, sum weighted by
+# the row's position from 1).  A different draw order, rule or layer moves
+# them by far more than the tolerance, which only absorbs rounding.
+SEED = 2 ** 33 + 17
+ROW = (np.arange(40) * 37 + 11) % 256
+DRAWN = {
+    "['blocks']['p0']['attn']['wk']": (0.13382303714752197, 369.70014703273773),
+    "['blocks']['p0']['attn']['wo']": (6.574276447296143, 740.1997742652893),
+    "['blocks']['p0']['attn']['wq']": (5.072579648345709, 743.4674925543368),
+    "['blocks']['p0']['attn']['wv']": (-6.767151594161987, 373.54111981391907),
+    "['blocks']['p0']['ln1']": (2.6155319213867188, 11.139411926269531),
+    "['blocks']['p0']['ln2']": (-0.43225860595703125, 9.086372375488281),
+    "['blocks']['p0']['mlp']['w_down']": (7.518952786922455, 1037.105009496212),
+    "['blocks']['p0']['mlp']['w_gate']": (-14.050286263227463,
+                                          1458.1338617503643),
+    "['blocks']['p0']['mlp']['w_up']": (-12.08928182721138, 1470.6593637764454),
+    "['embed']['tok']": (9.566559873521328, 1483.0394733920693),
+    "['final_norm']": (0.2711033821105957, 4.817612171173096),
+}
+HIDDEN = (140.07219859113684, 2040.6658977320767, 1394.2392806546995)
+LOGITS = (-191.26699419791112, 7323.531036352913, -7281.21058804763)
+
+
+@pytest.fixture(scope="module")
+def dense_params():
+    import functools
+
+    bundle = DENSE.build(TINY)
+    return model.make_params(bundle, functools.partial(DENSE.draw, TINY),
+                             SEED)
+
+
+def test_the_dense_draw_has_not_moved(dense_params):
+    import jax
+
+    got = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(dense_params)[0]:
+        a = np.asarray(leaf.astype(np.float32), np.float64)
+        got[jax.tree_util.keystr(path)] = (a.sum(), np.abs(a).sum())
+    assert set(got) == set(DRAWN)
+    for name, want in DRAWN.items():
+        assert got[name] == pytest.approx(want, rel=1e-6, abs=1e-6), name
+
+
+def test_the_dense_reference_has_not_moved(dense_params):
+    import jax.numpy as jnp
+
+    h = DENSE.hidden(TINY, "f32", dense_params, jnp.asarray(ROW, jnp.int32))
+    lg = DENSE.logits("f32", dense_params, h)
+    pos = np.arange(1, len(ROW) + 1)[:, None]
+    for got, want in ((h, HIDDEN), (lg, LOGITS)):
+        a = np.asarray(got, np.float64)
+        assert (a.sum(), np.abs(a).sum(), (a * pos).sum()) == \
+            pytest.approx(want, rel=1e-5)
+
+
+@pytest.mark.parametrize("family,match", [(None, "names no model family"),
+                                          ("no_such_family", "no module")])
+def test_a_config_without_a_family_module_is_refused(tmp_path, family,
+                                                     match):
+    conf = {k: v for k, v in TINY.items() if k != "family"}
+    if family:
+        conf["family"] = family
+    os.makedirs(tmp_path / "chipbench" / "configs")
+    with open(tmp_path / "chipbench" / "configs" / "tiny.json", "w") as f:
+        json.dump(conf, f)
+    with pytest.raises(ValueError, match=match) as err:
+        model.load_config(str(tmp_path), "tiny")
+    assert "tiny.json" in str(err.value)
 
 
 def test_unknown_device_kind_is_an_error():
@@ -275,6 +366,8 @@ def test_benchmark_json_names_files_that_exist():
         conf = json.load(open(os.path.join(REPO, c["file"])))
         assert conf["reduced"] == c["reduced"]
         assert c["source"] == conf["source"]
+        assert os.path.exists(os.path.join(
+            REPO, "chipbench", "families", f"{conf['family']}.py"))
     e2e = {m["name"] for m in BENCH["end_to_end"]}
     for w in BENCH["workloads"]:
         assert os.path.exists(os.path.join(
